@@ -7,14 +7,9 @@ call, 1 device dispatch per factorization independent of the panel count,
 Run with ``PYTHONPATH=src`` for the standalone numbers, or with ``--guard``
 for the CI tier-1 retrace guard (exits non-zero if any guarded entry point
 re-traces on a second call with identical shapes)."""
-import os
-import sys
+from repro.launch.env import force_host_devices
 
-if "jax" not in sys.modules:           # must precede the first jax import
-    flag = "--xla_force_host_platform_device_count=8"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+force_host_devices(8)                  # on the CPU; precedes the first jax import
 
 from repro.bench.cases.dispatch import case, guard, main, run  # noqa: E402,F401
 

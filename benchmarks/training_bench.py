@@ -7,14 +7,9 @@ with the dense non-FT baseline, and survivor/recovery counts for the model
 zoo under the cascading and BLANK-under-repeat schedules.
 
 Run with ``PYTHONPATH=src`` (needs ≥ 4 devices; the bench CLI forces 8)."""
-import os
-import sys
+from repro.launch.env import force_host_devices
 
-if "jax" not in sys.modules:           # must precede the first jax import
-    flag = "--xla_force_host_platform_device_count=8"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+force_host_devices(8)                  # on the CPU; precedes the first jax import
 
 from repro.bench.cases.training import PARITY_TOL, case  # noqa: E402,F401
 

@@ -9,20 +9,21 @@ variant without interrupting the run.
 
 Reports data-axis bytes: compressed vs dense all-reduce.
 
-  python examples/powersgd_dp.py          # sets its own XLA_FLAGS
+  JAX_PLATFORMS=cpu python examples/powersgd_dp.py   # 8 host devices
 """
 import os
+import sys
 
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+from repro.launch.env import force_host_devices      # noqa: E402
+
+force_host_devices(8)            # on the CPU; precedes the first jax import
 
 import jax                                           # noqa: E402
 import jax.numpy as jnp                              # noqa: E402
 from jax import lax                                  # noqa: E402
 from jax.sharding import PartitionSpec as P           # noqa: E402
-
-import sys                                           # noqa: E402
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.collective import FaultSpec, ShardMapComm  # noqa: E402
 from repro.compat import make_mesh, shard_map        # noqa: E402

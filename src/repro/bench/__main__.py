@@ -1,37 +1,27 @@
 """``python -m repro.bench`` — run / compare / list.
 
-``run`` forces a multi-device host platform (default 8 simulated CPU
-devices via ``XLA_FLAGS``) *before* jax is imported, so the trainer-level
-fault scenarios (SHRINK / REBUILD / BLANK over a real data axis) execute
-against a genuine multi-replica mesh even on a laptop.  ``compare`` and
-``list`` never import jax.
+On the CPU (``JAX_PLATFORMS=cpu``), ``run`` forces 8 simulated host
+devices *before* jax is imported, so the trainer-level fault scenarios
+(SHRINK / REBUILD / BLANK over a real data axis) execute against a
+multi-replica mesh even on a laptop.  On an accelerator the scenarios run
+on the chips, and one that needs more chips than the host has fails the
+run.  ``run`` keeps compiled programs in the persistent compilation cache
+(:func:`repro.launch.env.enable_compile_cache`).  ``compare`` and ``list``
+never import jax.
 """
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 __all__ = ["main"]
 
-_DEVICE_FLAG = "--xla_force_host_platform_device_count"
-
-
-def _force_devices(n: int) -> None:
-    if n <= 0:
-        return
-    if "jax" in sys.modules:
-        # too late to change the platform; scenarios will skip if starved
-        print(f"[bench] jax already imported; cannot force {n} host devices",
-              file=sys.stderr)
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    if _DEVICE_FLAG not in flags:
-        os.environ["XLA_FLAGS"] = f"{flags} {_DEVICE_FLAG}={n}".strip()
-
 
 def _cmd_run(args) -> int:
-    _force_devices(args.devices)
+    from repro.launch.env import enable_compile_cache, force_host_devices
+
+    force_host_devices(args.devices)
+    enable_compile_cache()
     # imports deferred until after the device-count env var is set
     from . import cases  # noqa: F401  — registers the benchmark cases
     from . import runner
@@ -83,8 +73,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="explicit output path (default: timestamped)")
     rp.add_argument("--out-dir", default="results/bench")
     rp.add_argument("--devices", type=int, default=8,
-                    help="forced host device count for trainer scenarios "
-                         "(0 = leave XLA_FLAGS alone)")
+                    help="host device count forced on the CPU for trainer "
+                         "scenarios (0 = leave XLA_FLAGS alone)")
     rp.set_defaults(fn=_cmd_run)
 
     cp = sub.add_parser("compare", help="gate a new run against a baseline")

@@ -1,11 +1,16 @@
 """Roofline analysis — reads the dry-run JSONs and derives the three terms
 per (arch × shape) cell on the single-pod mesh (EXPERIMENTS.md §Roofline).
 
-  compute    = HLO_FLOPs/device        / 197e12  (bf16 peak, TPU v5e)
-  memory     = HLO_bytes/device        / 819e9   (HBM bw)
-  collective = collective_bytes/device / 50e9    (per-link ICI, conservative
+  compute    = HLO_FLOPs/device        / peak bf16 FLOP/s
+  memory     = HLO_bytes/device        / peak HBM bytes/s
+  collective = collective_bytes/device / per-link ICI bytes/s (conservative
                single-link figure; result-shape bytes of every collective in
                the partitioned HLO, async pairs deduped)
+
+The peaks come from :data:`PEAKS`, keyed by the chip's ``device_kind``:
+the chip the run is on where that is a TPU, else the chip the dry-run
+meshes describe (:data:`TARGET_KIND`).  A TPU missing from the table is an
+error, never a default.
 
 HLO FLOP/byte totals come from the unrolled accounting extrapolation
 (``accounting.extrapolated``) because XLA's HloCostAnalysis counts scan
@@ -33,12 +38,37 @@ import numpy as np
 from repro.bench.registry import bench_case
 from repro.bench.schema import Metric
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks, keyed by jax's ``device_kind``.  TPU v5e:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s ICI; the per-link figure is a conservative 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# The chip the dry-run meshes (16x16 single pod) describe.
+TARGET_KIND = "TPU v5 lite"
 
-__all__ = ["advice", "analyze_record", "case", "cqr2_rows", "load_all",
-           "main", "markdown_table", "tuned_markdown", "tuned_tables"]
+
+def peaks(device_kind: str | None = None) -> dict:
+    """Peaks of ``device_kind`` — by default the TPU this process runs on,
+    or :data:`TARGET_KIND` when it runs on no TPU.  Raises for a kind that
+    is not in :data:`PEAKS`."""
+    if device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        device_kind = dev.device_kind if dev.platform == "tpu" else TARGET_KIND
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add its "
+            "FLOP/s, HBM and ICI figures with their source to PEAKS"
+        ) from None
+
+
+__all__ = ["PEAKS", "TARGET_KIND", "advice", "analyze_record", "case",
+           "cqr2_rows", "load_all", "main", "markdown_table", "peaks",
+           "tuned_markdown", "tuned_tables"]
 
 # Reference tall-skinny shapes for the CQR2 HBM model (per-rank panels of
 # the production TSQR: m_local × n at bf16).
@@ -46,7 +76,7 @@ CQR2_SHAPES = ((1 << 20, 128), (1 << 22, 256), (1 << 24, 512))
 
 
 def cqr2_rows(shapes=CQR2_SHAPES, dtype: str = "bfloat16",
-              hbm_bw: float = HBM_BW) -> list[dict]:
+              hbm_bw: float | None = None) -> list[dict]:
     """HBM-traffic model of CholeskyQR2, fused vs unfused pipelines.
 
     The coefficients are *measured*, not restated: each pipeline runs at two
@@ -62,6 +92,7 @@ def cqr2_rows(shapes=CQR2_SHAPES, dtype: str = "bfloat16",
 
     from repro.kernels import ops, traffic
 
+    hbm_bw = hbm_bw or peaks()["hbm_bw"]
     dt = jnp.dtype(dtype)
     pipelines = {
         "unfused": lambda a: ops.cholesky_qr2(a, fused=False),
@@ -195,15 +226,16 @@ def analyze_record(rec: dict) -> dict | None:
         "cost.bytes accessed", rec["cost"].get("bytes accessed", 0.0)
     )
     coll_dev = ext.get("coll.total_bytes", rec["collectives"]["total_bytes"])
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    peak = peaks()
+    t_compute = flops_dev / peak["flops"]
+    t_memory = bytes_dev / peak["hbm_bw"]
+    t_coll = coll_dev / peak["ici_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(cfg, rec["kind"], rec["global_batch"], rec["seq_len"])
     ratio = mf / (flops_dev * n_dev) if flops_dev else 0.0
     bound = max(terms.values())
-    frac = (mf / n_dev / PEAK_FLOPS) / bound if bound else 0.0
+    frac = (mf / n_dev / peak["flops"]) / bound if bound else 0.0
     return {
         "arch": rec["arch"], "shape": rec["shape"], "kind": rec["kind"],
         "compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll,

@@ -30,7 +30,7 @@ import tempfile
 
 import numpy as np
 
-from repro.bench.registry import BenchFailure, SkipCase, bench_case
+from repro.bench.registry import BenchFailure, bench_case, require_devices
 from repro.bench.schema import Metric
 
 __all__ = ["case", "PARITY_TOL"]
@@ -198,13 +198,7 @@ def _zoo_scenarios(archs: tuple) -> dict:
 
 def case(archs: tuple = ("qwen2-moe-a2.7b", "mamba2-2.7b"),
          parity_steps: int = 6) -> dict:
-    import jax
-
-    if jax.device_count() < _DATA_WIDTH:
-        raise SkipCase(
-            f"needs {_DATA_WIDTH} devices, have {jax.device_count()} "
-            "(run via `python -m repro.bench run`, which forces 8)"
-        )
+    require_devices(_DATA_WIDTH)
     hard = dict(gate="hard", direction="exact")
     metrics: dict[str, Metric] = {}
 

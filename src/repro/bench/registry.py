@@ -8,7 +8,8 @@ and timing policy and records it in :data:`REGISTRY`.  The runner
 assembles the schema document.
 
 Cases signal environmental impossibility (missing artifacts, too few
-devices) by raising :class:`SkipCase`, and a *measured property violation*
+simulated CPU devices) by raising :class:`SkipCase` — too few *chips* is a
+failure, see :func:`require_devices` — and a *measured property violation*
 — e.g. the paper's within-tolerance survival guarantee failing — by
 raising :class:`BenchFailure`, which fails the whole run loudly (non-zero
 exit) rather than burying the violation in a metric nobody reads.
@@ -26,6 +27,7 @@ __all__ = [
     "TIERS",
     "bench_case",
     "cases_for",
+    "require_devices",
 ]
 
 TIERS = ("smoke", "full")
@@ -37,6 +39,26 @@ class SkipCase(Exception):
 
 class BenchFailure(Exception):
     """Raised by a case whose measured invariant is violated (loud failure)."""
+
+
+def require_devices(n: int) -> None:
+    """Stop a case that needs ``n`` devices on a host with fewer.  On the
+    CPU that is a :class:`SkipCase` (the host devices are simulated; the
+    bench CLI forces 8).  On an accelerator the devices are the chips the
+    run is meant to measure, so too few is a :class:`BenchFailure`."""
+    import jax
+
+    have = jax.device_count()
+    if have >= n:
+        return
+    if jax.default_backend() == "cpu":
+        raise SkipCase(
+            f"needs {n} devices, have {have} (run via `JAX_PLATFORMS=cpu "
+            "python -m repro.bench run`, which forces 8 host devices)"
+        )
+    raise BenchFailure(
+        f"needs {n} devices, have {have} {jax.devices()[0].device_kind}"
+    )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -44,7 +44,7 @@ from collections.abc import Mapping
 
 import numpy as np
 
-from repro.bench.registry import BenchFailure, SkipCase, bench_case
+from repro.bench.registry import BenchFailure, SkipCase, bench_case, require_devices
 from repro.bench.schema import Metric
 
 __all__ = [
@@ -365,18 +365,11 @@ def run_blocked_qr_scenario(sc: BlockedQRScenario, seed: int = 0) -> dict:
 def run_trainer_scenario(sc: TrainerScenario, ckpt_dir: str | None = None) -> dict:
     """Drive a tiny Trainer through the event schedule; metric dict.
 
-    Raises :class:`~repro.bench.registry.SkipCase` when the host has too
-    few devices — anything else (I/O errors included) propagates and fails
-    the run loudly.
+    Raises :class:`~repro.bench.registry.SkipCase` when a CPU host has too
+    few devices (:func:`~repro.bench.registry.require_devices`) — anything
+    else (I/O errors, too few chips) propagates and fails the run loudly.
     """
-    import jax
-
-    n_needed = sc.data_width * sc.model_width
-    if jax.device_count() < n_needed:
-        raise SkipCase(
-            f"needs {n_needed} devices, have {jax.device_count()} "
-            "(run via `python -m repro.bench run`, which forces 8)"
-        )
+    require_devices(sc.data_width * sc.model_width)
     from repro.compat import make_mesh
     from repro.configs.base import get_config
     from repro.data.pipeline import DataConfig
@@ -612,7 +605,7 @@ def case(include_trainer: bool = True, seed: int = 0):
                 **({"seed": seed} if sc.kind in ("collective", "blocked")
                    else {}),
             )
-        except SkipCase as e:       # too few devices; real errors propagate
+        except SkipCase as e:   # too few CPU devices; real errors propagate
             metrics[f"{sc.name}.skipped"] = Metric(
                 True, gate="warn", direction="exact"
             )

@@ -1,108 +1,38 @@
-"""Version-compatibility shims for the installed jax.
+"""Mesh and ``shard_map`` construction, made in one place.
 
-The repo targets recent jax (the explicit-sharding era:
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.shard_map(..., check_vma=...)``) but must degrade gracefully on older
-releases (0.4.x) where those names/kwargs do not exist.  Everything in the
-repo that builds a mesh or enters ``shard_map`` goes through this module so
-the compatibility decision is made exactly once.
+Everything in the repo that builds a mesh or enters ``shard_map`` goes
+through this module, so the two policies below are set exactly once:
+every mesh axis is ``AxisType.Auto``, and ``shard_map`` runs with its
+varying-manual-axes check off.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
-    "AxisType",
-    "HAS_AXIS_TYPES",
-    "axis_types_kwargs",
     "make_mesh",
     "mesh_fingerprint",
     "mesh_from_devices",
-    "optimization_barrier",
     "shard_map",
 ]
 
 
-# -- lax.optimization_barrier under vmap ------------------------------------
-#
-# The trailing-update oracle (repro.kernels.ref) uses optimization_barrier
-# to pin XLA rewrites so the eager driver and the scan pipeline stay
-# bitwise-comparable at narrow panel widths — but jax (through at least
-# 0.4.37) never registered a vmap batching rule for the primitive, which
-# breaks the batched (vmapped) pipeline.  The barrier is an identity on
-# every leaf, so the rule is trivial: bind through, dims unchanged.  When
-# the internal primitive moves, fall back to the identity function (vmap
-# keeps working; the last-ulp pinning is best-effort by nature).
-
-def _make_optimization_barrier():
-    try:
-        from jax import lax
-
-        barrier = lax.optimization_barrier
-    except (ImportError, AttributeError):
-        return lambda x: x
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-
-        if optimization_barrier_p not in batching.primitive_batchers:
-            def _batch_rule(args, dims):
-                return optimization_barrier_p.bind(*args), dims
-
-            batching.primitive_batchers[optimization_barrier_p] = _batch_rule
-    except (ImportError, AttributeError):
-        # Private primitive moved but the public op still exists: keep the
-        # barrier (the single-matrix bit-identity contract depends on it)
-        # and let vmapped narrow-width calls fail loudly — a silent
-        # identity here would surface as mysterious last-ulp mismatches in
-        # the hypothesis sweep instead of an error pointing at this shim.
-        pass
-    return barrier
-
-
-optimization_barrier = _make_optimization_barrier()
-
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # older jax: meshes are implicitly "auto" everywhere
-
-    class AxisType:  # type: ignore[no-redef]
-        """Stand-in so ``(AxisType.Auto,) * n`` spellings keep working."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    HAS_AXIS_TYPES = False
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` when the installed jax understands it."""
-    if HAS_AXIS_TYPES:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-    return {}
+def _auto(n_axes: int) -> tuple:
+    return (AxisType.Auto,) * n_axes
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types when supported."""
-    try:
-        return jax.make_mesh(
-            axis_shapes, axis_names, **axis_types_kwargs(len(axis_names))
-        )
-    except TypeError:  # make_mesh predates the axis_types kwarg
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=_auto(len(axis_names))
+    )
 
 
 def mesh_from_devices(devices, axis_names) -> Mesh:
     """``Mesh(devices, names)`` from an explicit device array (elastic
-    shrink/rebuild paths), with Auto axis types when supported."""
-    try:
-        return Mesh(devices, axis_names, **axis_types_kwargs(len(axis_names)))
-    except TypeError:
-        return Mesh(devices, axis_names)
+    shrink/rebuild paths), with Auto axis types."""
+    return Mesh(devices, axis_names, axis_types=_auto(len(axis_names)))
 
 
 def mesh_fingerprint(mesh: Mesh) -> tuple:
@@ -121,24 +51,10 @@ def mesh_fingerprint(mesh: Mesh) -> tuple:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Replication/VMA checking is disabled in all cases: the collective engine
-    mixes host-planned ``ppermute`` routes with per-rank control values,
-    which the static checkers cannot type.
+    """``jax.shard_map`` with the varying-manual-axes check disabled: the
+    collective engine mixes host-planned ``ppermute`` routes with per-rank
+    control values, which the static checker cannot type.
     """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # pre-check_vma spelling
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

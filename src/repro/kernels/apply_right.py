@@ -22,7 +22,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import autotune as _autotune
-from .backend import pick_block_rows, resolve_backend
+from .backend import dot_precision, pick_block_rows, resolve_backend
 from .dispatch import note_trace
 
 __all__ = ["apply_right"]
@@ -33,6 +33,7 @@ def _apply_kernel(a_ref, w_ref, o_ref):
         a_ref[...],
         w_ref[...],
         (((1,), (0,)), ((), ())),
+        precision=dot_precision(a_ref.dtype),
         preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 

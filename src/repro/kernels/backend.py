@@ -48,8 +48,10 @@ import jax
 __all__ = [
     "Backend",
     "DEFAULT_BLOCK_ROWS",
+    "F32_PRECISION",
     "KINDS",
     "default_interpret",
+    "dot_precision",
     "pick_block_rows",
     "resolve_backend",
     "resolve_interpret",
@@ -61,6 +63,19 @@ KINDS = ("tpu-mosaic", "gpu-triton", "interpret")
 # ``gram`` for compatibility; the autotuner treats it as the baseline
 # candidate every measured search must include.
 DEFAULT_BLOCK_ROWS = 1024
+
+# The precision of every f32 matmul on the QR path, Pallas and XLA alike.
+# A TPU's default f32 matmul rounds its operands to bfloat16, and CholeskyQR
+# squares the condition number in its Gram, so the factors need all f32 bits.
+# The CPU ignores the setting: its f32 matmul is exact f32 either way.
+F32_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def dot_precision(dtype):
+    """The precision of a Pallas kernel's dot over ``dtype`` operands:
+    :data:`F32_PRECISION` for float32, the MXU's native single pass (None)
+    for bfloat16, which Mosaic refuses to run at the f32 setting."""
+    return F32_PRECISION if jax.numpy.dtype(dtype) == jax.numpy.float32 else None
 
 _TPU_SUBLANE = 8
 _GPU_SUBLANE = 16

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .backend import resolve_interpret
+from .backend import dot_precision, resolve_interpret
 from .dispatch import note_trace
 
 __all__ = ["combine_gram"]
@@ -30,9 +30,9 @@ def _combine_kernel(r1_ref, r2_ref, o_ref):
     r1 = r1_ref[...]
     r2 = r2_ref[...]
     dims = (((0,), (0,)), ((), ()))
-    o_ref[...] = lax.dot_general(
-        r1, r1, dims, preferred_element_type=jnp.float32
-    ) + lax.dot_general(r2, r2, dims, preferred_element_type=jnp.float32)
+    kw = dict(precision=dot_precision(r1.dtype), preferred_element_type=jnp.float32)
+    o_ref[...] = (lax.dot_general(r1, r1, dims, **kw)
+                  + lax.dot_general(r2, r2, dims, **kw))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -38,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import autotune as _autotune
-from .backend import pick_block_rows, resolve_backend
+from .backend import dot_precision, pick_block_rows, resolve_backend
 from .dispatch import note_trace
 from .gram import mask_rows
 
@@ -59,13 +59,15 @@ def _fused_kernel(a_ref, w_ref, *out_refs, block_rows: int, m: int,
 
     a = mask_rows(a_ref[...], i, block_rows, m)
     q32 = lax.dot_general(
-        a, w_ref[...], _APPLY_DIMS, preferred_element_type=jnp.float32
+        a, w_ref[...], _APPLY_DIMS, precision=dot_precision(a.dtype),
+        preferred_element_type=jnp.float32
     )
     q = q32.astype(a_ref.dtype)
     if want_q:
         out_refs[0][...] = q
     g_ref[...] += lax.dot_general(
-        q, q, _GRAM_DIMS, preferred_element_type=jnp.float32
+        q, q, _GRAM_DIMS, precision=dot_precision(q.dtype),
+        preferred_element_type=jnp.float32
     )
 
 

@@ -33,7 +33,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import autotune as _autotune
-from .backend import DEFAULT_BLOCK_ROWS, pick_block_rows, resolve_backend
+from .backend import DEFAULT_BLOCK_ROWS, dot_precision, pick_block_rows, resolve_backend
 from .dispatch import note_trace
 
 __all__ = [
@@ -76,7 +76,8 @@ def _gram_kernel(a_ref, o_ref, *, block_rows: int, m: int):
 
     a = mask_rows(a_ref[...], i, block_rows, m)
     o_ref[...] += lax.dot_general(
-        a, a, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, a, (((0,), (0,)), ((), ())), precision=dot_precision(a.dtype),
+        preferred_element_type=jnp.float32
     )
 
 

@@ -37,7 +37,7 @@ from . import gram as _gram_mod
 from . import ref as _ref
 from . import traffic as _traffic
 from . import trailing_update as _trailing_mod
-from .backend import resolve_backend
+from .backend import F32_PRECISION, resolve_backend
 
 __all__ = [
     "gram",
@@ -355,7 +355,7 @@ def cholesky_qr2(a, *, use_pallas: bool = False, interpret: bool | None = None,
     if not fused:
         q1, r1 = cholesky_qr(a, use_pallas=use_pallas, interpret=interpret)
         q, r2 = cholesky_qr(q1, use_pallas=use_pallas, interpret=interpret)
-        return q, _posdiag(r2 @ r1)
+        return q, _posdiag(jnp.matmul(r2, r1, precision=F32_PRECISION))
     g1 = gram(a, use_pallas=use_pallas, interpret=interpret)       # sweep 1
     r1 = _chol_upper(g1)
     q1, g2 = fused_apply_gram(                                     # sweep 2
@@ -367,7 +367,7 @@ def cholesky_qr2(a, *, use_pallas: bool = False, interpret: bool | None = None,
         q1, tri_inv(r2).astype(a.dtype),
         use_pallas=use_pallas, interpret=interpret,
     )
-    return q, _posdiag(r2 @ r1)
+    return q, _posdiag(jnp.matmul(r2, r1, precision=F32_PRECISION))
 
 
 def cholesky_qr2_r(a, *, use_pallas: bool = False,
@@ -389,4 +389,4 @@ def cholesky_qr2_r(a, *, use_pallas: bool = False,
         use_pallas=use_pallas, interpret=interpret, want_q=False,
     )
     r2 = _chol_upper(g2)
-    return _posdiag(r2 @ r1)
+    return _posdiag(jnp.matmul(r2, r1, precision=F32_PRECISION))

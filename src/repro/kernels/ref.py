@@ -6,6 +6,9 @@ function of the same name here.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax.lax import optimization_barrier
+
+from .backend import F32_PRECISION
 
 __all__ = [
     "gram",
@@ -23,12 +26,13 @@ __all__ = [
 def gram(a: jnp.ndarray) -> jnp.ndarray:
     """G = AᵀA accumulated in float32.  a: (..., m, n) → (..., n, n) f32."""
     a32 = a.astype(jnp.float32)
-    return jnp.einsum("...mi,...mj->...ij", a32, a32)
+    return jnp.einsum("...mi,...mj->...ij", a32, a32, precision=F32_PRECISION)
 
 
 def apply_right(a: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """A @ W with float32 accumulation, result in A's dtype.  w: (..., n, k)."""
-    out = a.astype(jnp.float32) @ w.astype(jnp.float32)
+    out = jnp.matmul(a.astype(jnp.float32), w.astype(jnp.float32),
+                     precision=F32_PRECISION)
     return out.astype(a.dtype)
 
 
@@ -86,16 +90,15 @@ def trailing_update(
     """Oracle for the fused trailing update: ``A_new = A − Q W`` (f32 math,
     stored in A's dtype) and, when ``next_width > 0``, the lookahead
     ``S = A_new[:, :next_width]ᵀ A_new`` of the *stored* (cast) update."""
-    from repro.compat import optimization_barrier
-
     nt = a.shape[-1]
     w32 = w.astype(jnp.float32)
     floor = min_gemm_width()
     if nt < floor:
-        wide = q.astype(jnp.float32) @ _pad_cols(w32, floor)
+        wide = jnp.matmul(q.astype(jnp.float32), _pad_cols(w32, floor),
+                          precision=F32_PRECISION)
         upd = optimization_barrier(wide)[..., :nt]
     else:
-        upd = q.astype(jnp.float32) @ w32
+        upd = jnp.matmul(q.astype(jnp.float32), w32, precision=F32_PRECISION)
     a_new = (a.astype(jnp.float32) - upd).astype(a.dtype)
     if not next_width:
         return a_new
@@ -104,16 +107,15 @@ def trailing_update(
 
 def panel_cross(a: jnp.ndarray, *, split: int) -> jnp.ndarray:
     """S = A[:, :split]ᵀ A accumulated in float32.  a: (..., m, n)."""
-    from repro.compat import optimization_barrier
-
     a32 = a.astype(jnp.float32)
     n = a.shape[-1]
     floor = min_gemm_width()
     if split >= floor and n >= floor:
-        return jnp.einsum("...mi,...mj->...ij", a32[..., :split], a32)
+        return jnp.einsum("...mi,...mj->...ij", a32[..., :split], a32,
+                          precision=F32_PRECISION)
     left = _pad_cols(a32[..., :split], floor)
     right = _pad_cols(a32, floor)
-    s = jnp.einsum("...mi,...mj->...ij", left, right)
+    s = jnp.einsum("...mi,...mj->...ij", left, right, precision=F32_PRECISION)
     return optimization_barrier(s)[..., :split, :n]
 
 
@@ -156,4 +158,4 @@ def cholesky_qr2(a: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """CholeskyQR2 — two rounds; the TPU-native tall-skinny QR."""
     q1, r1 = cholesky_qr(a)
     q, r2 = cholesky_qr(q1)
-    return q, _posdiag(r2 @ r1)
+    return q, _posdiag(jnp.matmul(r2, r1, precision=F32_PRECISION))

@@ -47,7 +47,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import autotune as _autotune
-from .backend import pick_block_rows, resolve_backend
+from .backend import dot_precision, pick_block_rows, resolve_backend
 from .dispatch import note_trace
 from .gram import mask_cols, mask_rows
 
@@ -61,7 +61,9 @@ def _update_kernel(a_ref, q_ref, w_ref, *out_refs, block_rows: int, m: int,
                    next_width: int):
     i = pl.program_id(0)
     upd = lax.dot_general(
-        q_ref[...], w_ref[...], _APPLY_DIMS, preferred_element_type=jnp.float32
+        q_ref[...], w_ref[...], _APPLY_DIMS,
+        precision=dot_precision(q_ref.dtype),
+        preferred_element_type=jnp.float32
     )
     a_new = (a_ref[...].astype(jnp.float32) - upd).astype(a_ref.dtype)
     out_refs[0][...] = a_new
@@ -75,6 +77,7 @@ def _update_kernel(a_ref, q_ref, w_ref, *out_refs, block_rows: int, m: int,
         a_m = mask_rows(a_new, i, block_rows, m)
         s_ref[...] += lax.dot_general(
             a_m[:, :next_width], a_m, _CROSS_DIMS,
+            precision=dot_precision(a_m.dtype),
             preferred_element_type=jnp.float32,
         )
 
@@ -148,7 +151,8 @@ def _cross_kernel(a_ref, s_ref, *, block_rows: int, m: int, split: int):
 
     a = mask_rows(a_ref[...], i, block_rows, m)
     s_ref[...] += lax.dot_general(
-        a[:, :split], a, _CROSS_DIMS, preferred_element_type=jnp.float32
+        a[:, :split], a, _CROSS_DIMS, precision=dot_precision(a.dtype),
+        preferred_element_type=jnp.float32
     )
 
 
@@ -201,7 +205,9 @@ def _pad_cross_kernel(a_ref, apad_ref, s_ref, *, block_rows: int, m: int,
     apad_ref[...] = a_p                 # OOB rows dropped on the edge write
     a_m = mask_rows(a_p, i, block_rows, m)
     s_ref[...] += lax.dot_general(
-        a_m[:, :split], a_m, _CROSS_DIMS, preferred_element_type=jnp.float32
+        a_m[:, :split], a_m, _CROSS_DIMS,
+        precision=dot_precision(a_m.dtype),
+        preferred_element_type=jnp.float32
     )
 
 

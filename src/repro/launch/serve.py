@@ -128,6 +128,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.env import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "qr":
         _serve_qr(args)
     else:

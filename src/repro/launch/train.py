@@ -20,8 +20,8 @@ the run's fault stats miss the scenario's expectations:
 from __future__ import annotations
 
 import argparse
-import os
-import sys
+
+from repro.launch.env import enable_compile_cache, force_host_devices
 
 
 def parse_events(fail: str, straggle: str, recover: str):
@@ -71,13 +71,9 @@ def main() -> None:
 
     sc = None
     if args.faults:
-        # Stock schedules need their full replica width; mirror the bench
-        # CLI and pin 8 host devices before the first jax import.
-        if "jax" not in sys.modules:
-            flag = "--xla_force_host_platform_device_count=8"
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "--xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+        # Stock schedules need their full replica width; on the CPU mirror
+        # the bench CLI and pin 8 host devices before the first jax import.
+        force_host_devices(8)
         from repro.bench.scenarios import get_scenarios
 
         stock = {s.name: s for s in get_scenarios() if s.kind == "trainer"}
@@ -89,6 +85,8 @@ def main() -> None:
         sc = stock[args.faults]
 
     import jax
+
+    enable_compile_cache()
 
     from repro.configs.base import get_config
     from repro.data.pipeline import DataConfig

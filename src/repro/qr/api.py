@@ -273,6 +273,7 @@ class QRConfig:
         return PanelFactorizer(
             local_qr="jnp" if local_r == "chol" else local_r,
             reorth=self.reorth,
+            interpret=self.interpret,
         )
 
 

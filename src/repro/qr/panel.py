@@ -39,8 +39,10 @@ from repro.collective.combiners import (
 from repro.collective.comm import Comm
 from repro.collective.engine import execute_plan, ft_allreduce
 from repro.collective.plan import Plan
+from repro.kernels.backend import F32_PRECISION
 
 __all__ = [
+    "CQR2PallasR",
     "FUSED_PANEL_COMBINER",
     "PanelFactorizer",
     "chol_r",
@@ -71,11 +73,21 @@ def qr_r_cqr2(a):
     return kops.cholesky_qr2_r(a)
 
 
-def qr_r_cqr2_pallas(a):
-    from repro.kernels import ops as kops
+@dataclasses.dataclass(frozen=True)
+class CQR2PallasR:
+    """CholeskyQR2 R factor on the Pallas kernels.  ``interpret`` reaches
+    every ``pl.pallas_call`` (``None`` auto-detects the backend).  A frozen
+    dataclass, so two equal instances hash alike and share jit caches."""
 
-    return kops.cholesky_qr2_r(a, use_pallas=True)
+    interpret: bool | None = None
 
+    def __call__(self, a):
+        from repro.kernels import ops as kops
+
+        return kops.cholesky_qr2_r(a, use_pallas=True, interpret=self.interpret)
+
+
+qr_r_cqr2_pallas = CQR2PallasR()
 
 local_qr_fns: dict[str, Callable] = {
     "jnp": qr_r_jnp,
@@ -84,7 +96,12 @@ local_qr_fns: dict[str, Callable] = {
 }
 
 
-def resolve_local_qr(local_qr: str | Callable) -> Callable:
+def resolve_local_qr(local_qr: str | Callable,
+                     interpret: bool | None = None) -> Callable:
+    """The local QR callable for ``local_qr``; ``interpret`` is threaded to
+    the Pallas kernels of ``"cqr2_pallas"``."""
+    if local_qr == "cqr2_pallas":
+        return CQR2PallasR(interpret)
     return local_qr_fns[local_qr] if isinstance(local_qr, str) else local_qr
 
 
@@ -137,11 +154,11 @@ def form_q(a_blocks, r, comm: Comm, reorth: int = 1):
 
     q = solve_r(a_blocks, r)
     for _ in range(reorth):
-        g = jnp.swapaxes(q, -1, -2) @ q
+        g = jnp.matmul(jnp.swapaxes(q, -1, -2), q, precision=F32_PRECISION)
         g_sum, _ = ft_allreduce(g, comm, op="gram_sum")
         r2 = _posdiag(jnp.swapaxes(jnp.linalg.cholesky(g_sum), -1, -2))
         q = solve_r(q, r2)
-        r = _posdiag(r2 @ r)
+        r = _posdiag(jnp.matmul(r2, r, precision=F32_PRECISION))
     return q, r
 
 
@@ -157,13 +174,15 @@ class PanelFactorizer:
     (…, m, n) panel to its (…, n, n) R factor; runs as the butterfly's
     ``prepare`` step.  ``reorth`` — CholeskyQR polish passes in
     :meth:`form_q` (each one Gram all-reduce over the same butterfly).
+    ``interpret`` — the Pallas backend flag of a ``"cqr2_pallas"`` local QR.
     """
 
     local_qr: str | Callable = "jnp"
     reorth: int = 1
+    interpret: bool | None = None
 
     def local_fn(self) -> Callable:
-        return resolve_local_qr(self.local_qr)
+        return resolve_local_qr(self.local_qr, self.interpret)
 
     def combiner(self) -> QRCombiner:
         return QRCombiner(self.local_fn())

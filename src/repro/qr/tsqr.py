@@ -49,6 +49,7 @@ from repro.collective.engine import ft_allreduce
 from repro.collective.faults import FaultSpec
 from repro.collective.plan import Plan, make_plan
 from repro.kernels import dispatch as _dispatch
+from repro.kernels.backend import F32_PRECISION
 
 from ._shard import dummy_q, shard_compile
 from .api import QRConfig, Redundancy, warn_deprecated_entry
@@ -131,7 +132,7 @@ def _compiled_tsqr_gram_shard(mesh, axis: str, p: int, reorth: int,
     def body(a_blk):
         _dispatch.note_trace("tsqr_gram_shard_map")
         a32 = a_blk.astype(jnp.float32)
-        g = jnp.einsum("mi,mj->ij", a32, a32)
+        g = jnp.einsum("mi,mj->ij", a32, a32, precision=F32_PRECISION)
         g, _ = ft_allreduce(g, comm, op="gram_sum")
         r = _posdiag(jnp.swapaxes(jnp.linalg.cholesky(g), -1, -2))
         q, r = form_q(a_blk, r, comm, reorth)

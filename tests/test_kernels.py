@@ -211,6 +211,36 @@ def test_interpret_flag_reaches_pallas_call(rng, monkeypatch):
     assert backend.default_interpret() is True           # CPU container
 
 
+@pytest.mark.parametrize("panel_width", [None, 8])
+def test_qrconfig_interpret_reaches_cqr2_local_qr(rng, monkeypatch,
+                                                   panel_width):
+    """``QRConfig.interpret`` reaches every ``pl.pallas_call`` of the
+    ``cqr2_pallas`` local QR, in the TSQR and the blocked driver alike."""
+    from jax.experimental import pallas as pl
+
+    from repro.kernels import fused_apply_gram as fused_mod
+    from repro.kernels import gram as gram_mod
+    from repro.qr import QRConfig, factorize
+
+    captured = []
+    real = pl.pallas_call
+
+    def spy(*args, **kw):
+        captured.append(kw.get("interpret"))
+        kw["interpret"] = True          # CPU cannot compile Mosaic
+        return real(*args, **kw)
+
+    for mod in (gram_mod, fused_mod):
+        monkeypatch.setattr(mod.pl, "pallas_call", spy, raising=True)
+
+    # unique shapes so jit can't replay a cached trace from earlier tests
+    a = jnp.asarray(rng.standard_normal((4, 37, 13)), dtype=jnp.float32)
+    cfg = QRConfig(panel_width=panel_width, local_r="cqr2_pallas",
+                   interpret=False, pipeline="off")
+    factorize(a, cfg)
+    assert captured and all(c is False for c in captured), captured
+
+
 # ---------------------------------------------------------------------------
 # GPU (Triton) lowerings: per-program partial accumulators vs the TPU
 # kernels' revisited-block accumulators — same math, parallel-grid-safe
