@@ -568,6 +568,12 @@ def execute_coded(
     reconstruction is then a *numerical* detection, not an echo of the
     fault spec.
     """
+    with _dispatch.span(_dispatch.REDUCE, rounds=plan.round_count(),
+                        messages=plan.message_count()):
+        return _execute_coded(x, comm, plan, combiner, observed)
+
+
+def _execute_coded(x, comm: Comm, plan: CodedPlan, combiner, observed):
     inner = get_combiner(combiner)
     if isinstance(inner, CodedCombiner):
         coded = inner

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.kernels import dispatch as _dispatch
+
 __all__ = ["Comm", "SimComm", "ShardMapComm"]
 
 Pair = tuple[int, int]
@@ -87,7 +89,8 @@ class SimComm(Comm):
             dst = jnp.array([d for _, d in perm], dtype=jnp.int32)
             return out.at[dst].set(leaf[src])
 
-        return jax.tree.map(go, x)
+        with _dispatch.span(_dispatch.EXCHANGE, messages=len(perm)):
+            return jax.tree.map(go, x)
 
     def bwhere(self, cond, a, b):
         a, b = jnp.broadcast_arrays(a, b)
@@ -120,7 +123,8 @@ class ShardMapComm(Comm):
                 return jnp.zeros_like(leaf)
             return lax.ppermute(leaf, self.axis, [tuple(p) for p in perm])
 
-        return jax.tree.map(go, x)
+        with _dispatch.span(_dispatch.EXCHANGE, messages=len(perm)):
+            return jax.tree.map(go, x)
 
     def bwhere(self, cond, a, b):
         return jnp.where(cond, a, b)

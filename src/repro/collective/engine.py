@@ -152,7 +152,16 @@ def execute_plan(
     plan permits; ``False`` forces the general executor; ``True`` demands
     the fast path (raises if the plan is not fault-free).
     """
-    combiner = get_combiner(combiner)
+    with _reduce_span(plan):
+        return _execute(x, comm, plan, get_combiner(combiner), fast)
+
+
+def _reduce_span(plan):
+    return _dispatch.span(_dispatch.REDUCE, rounds=plan.round_count(),
+                          messages=plan.message_count())
+
+
+def _execute(x, comm: Comm, plan: Plan, combiner: Combiner, fast):
     fault_free = plan.is_fault_free
     if fast is True and not fault_free:
         raise ValueError(
@@ -228,13 +237,18 @@ def replica_fetch(x, comm: Comm, valid) -> object:
     pairs = [
         (int(donors[i % len(donors)]), int(r)) for i, r in enumerate(starved)
     ]
-    for rnd in _split_rounds(pairs):
-        got = np.zeros(valid.shape[0], dtype=bool)
-        got[[d for _, d in rnd]] = True
-        g = comm.take(got)
-        recv = comm.exchange(x, rnd)
-        x = jax.tree.map(lambda cur, rec: comm.bwhere(g, rec, cur), x, recv)
-    return x
+    rounds = _split_rounds(pairs)
+    with _dispatch.span(_dispatch.RECOVER, restored=len(starved),
+                        rounds=len(rounds)):
+        for rnd in rounds:
+            got = np.zeros(valid.shape[0], dtype=bool)
+            got[[d for _, d in rnd]] = True
+            g = comm.take(got)
+            recv = comm.exchange(x, rnd)
+            x = jax.tree.map(
+                lambda cur, rec: comm.bwhere(g, rec, cur), x, recv
+            )
+        return x
 
 
 def recover_payload(x, comm: Comm, valid, *, plan=None) -> object:
@@ -292,9 +306,9 @@ def ft_allreduce(
     if plan is None:
         plan = make_plan(variant, comm.n_ranks, fault_spec)
     combiner = get_combiner(op)
-    val, valid = execute_plan(x, comm, plan, combiner, fast=fast)
-    val = combiner.tree_finalize(val, plan.n_ranks)
-    return val, valid
+    with _reduce_span(plan):
+        val, valid = _execute(x, comm, plan, combiner, fast)
+        return combiner.tree_finalize(val, plan.n_ranks), valid
 
 
 # ---------------------------------------------------------------------------

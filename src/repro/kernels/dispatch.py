@@ -32,6 +32,15 @@ Usage::
 
 The global trace counters are monotonic for the life of the process (they
 survive ``track_dispatch`` scopes), so retrace guards compare deltas.
+
+**Spans.**  :func:`span` opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``: a host span in the profiler's own trace, on the clock
+of its device planes, carrying small integer counts as its stats.  With
+no profiler session it records nothing.  While a body is traced, the
+same name also enters the JAX name stack (``jax.named_scope``), so the
+device operations of a compiled body carry it in their ``op_name``
+metadata.  The names are the constants below: one vocabulary for the
+host spans and the device scopes of a ``factorize`` call.
 """
 from __future__ import annotations
 
@@ -39,16 +48,32 @@ import collections
 import contextlib
 import dataclasses
 
+import jax
+
 __all__ = [
     "DispatchStats",
     "note_dispatch",
-    "note_overlap",
-    "note_rounds",
     "note_trace",
+    "span",
     "suppress",
     "trace_count",
     "track_dispatch",
 ]
+
+SPAN_PREFIX = "repro."
+
+# The span vocabulary, from the entry point down (PERF.md §3).
+FACTORIZE = "factorize"              # one whole factorize call
+PLAN = "plan"                        # host planning and accounting
+LAUNCH = "launch"                    # a compiled whole-factorization program
+PANEL = "panel"                      # one panel of the eager blocked driver
+LOCAL_R = "local_r"                  # each rank's R before the butterfly
+REDUCE = "reduce"                    # one butterfly (or coded) reduction
+EXCHANGE = "exchange"                # one permutation between ranks
+RECOVER = "recover"                  # restoring lost ranks from replicas
+FORM_Q = "form_q"                    # explicit Q and its polish passes
+BLOCK_ROW = "block_row"              # the panel's block row of R
+TRAILING_UPDATE = "trailing_update"  # the sweep over the trailing block
 
 # Monotonic per-name trace counts for the whole process (retrace guards
 # compare before/after deltas; never reset).
@@ -65,14 +90,6 @@ class DispatchStats:
     dispatches: collections.Counter = dataclasses.field(
         default_factory=collections.Counter
     )
-    # Serial butterfly rounds per entry point (the collective latency
-    # proxy) and how many of its reductions were overlapped with compute.
-    rounds: collections.Counter = dataclasses.field(
-        default_factory=collections.Counter
-    )
-    overlapped: collections.Counter = dataclasses.field(
-        default_factory=collections.Counter
-    )
 
     @property
     def n_traces(self) -> int:
@@ -82,20 +99,10 @@ class DispatchStats:
     def n_dispatches(self) -> int:
         return sum(self.dispatches.values())
 
-    @property
-    def n_rounds(self) -> int:
-        return sum(self.rounds.values())
-
-    @property
-    def n_overlapped(self) -> int:
-        return sum(self.overlapped.values())
-
     def as_dict(self) -> dict:
         return {
             "traces": dict(self.traces),
             "dispatches": dict(self.dispatches),
-            "rounds": dict(self.rounds),
-            "overlapped": dict(self.overlapped),
         }
 
 
@@ -120,26 +127,6 @@ def note_dispatch(name: str, n: int = 1) -> None:
         return
     for t in _ACTIVE:
         t.dispatches[name] += n
-
-
-def note_rounds(name: str, n: int = 1) -> None:
-    """Record ``n`` serial collective (butterfly) rounds committed by the
-    named entry point — one per exchange level, priced from the host plan
-    (no-op when nothing is tracking or inside :func:`suppress`)."""
-    if not _ACTIVE or _SUPPRESS:
-        return
-    for t in _ACTIVE:
-        t.rounds[name] += n
-
-
-def note_overlap(name: str, n: int = 1) -> None:
-    """Record ``n`` reductions issued against lookahead accumulators while
-    the previous panel's trailing sweep runs (the double-buffered pipeline's
-    comm/compute overlap depth)."""
-    if not _ACTIVE or _SUPPRESS:
-        return
-    for t in _ACTIVE:
-        t.overlapped[name] += n
 
 
 def trace_count(name: str | None = None) -> int:
@@ -173,3 +160,30 @@ def suppress():
         yield
     finally:
         _SUPPRESS.pop()
+
+
+class span:
+    """``with span(name, **counts):`` — the span ``repro.<name>`` around the
+    block, with ``counts`` (small ints the caller already holds) as its
+    stats.  While a body is traced the name also enters the name stack.
+    Without a profiler session and outside a trace it records nothing."""
+
+    __slots__ = ("_annotation", "_scope")
+
+    def __init__(self, name: str, **counts: int):
+        name = SPAN_PREFIX + name
+        self._annotation = jax.profiler.TraceAnnotation(name, **counts)
+        self._scope = (
+            None if jax.core.trace_ctx.is_top_level() else jax.named_scope(name)
+        )
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        if self._scope is not None:
+            self._scope.__enter__()
+        return self._annotation
+
+    def __exit__(self, *exc):
+        if self._scope is not None:
+            self._scope.__exit__(*exc)
+        self._annotation.__exit__(*exc)

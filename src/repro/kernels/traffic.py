@@ -34,7 +34,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 
-__all__ = ["KernelTraffic", "note", "suppress", "track_traffic"]
+__all__ = ["KernelTraffic", "note", "suppress", "track_traffic", "tracking"]
 
 
 @dataclasses.dataclass
@@ -166,6 +166,11 @@ def note(op: str, *, sweeps: int = 0, read_bytes: int = 0,
     }
     for t in _ACTIVE:
         t.records.append(rec)
+
+
+def tracking() -> bool:
+    """Would :func:`note` record anything here?"""
+    return bool(_ACTIVE) and not _SUPPRESS
 
 
 @contextlib.contextmanager

@@ -38,10 +38,12 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import itertools
 import warnings
 
 from repro.collective.faults import FaultSpec
 from repro.collective.plan import VARIANTS
+from repro.kernels import dispatch as _dispatch
 
 __all__ = [
     "Fuse",
@@ -296,6 +298,11 @@ def warn_deprecated_entry(name: str) -> None:
 # The facade
 # ---------------------------------------------------------------------------
 
+# Process-wide call numbers: the ``call`` count of each ``repro.factorize``
+# span, which the spans nested in it share.
+_CALLS = itertools.count()
+
+
 def _route_error(a, mesh) -> str:
     return (
         f"cannot route input of shape {getattr(a, 'shape', None)} with "
@@ -332,7 +339,6 @@ def factorize(
     :class:`~repro.qr.blocked.BlockedQRResult` accordingly.
     """
     from . import blocked as _blocked
-    from . import tsqr as _tsqr
 
     if config is None:
         config = QRConfig()
@@ -343,6 +349,7 @@ def factorize(
             "defaults) rather than passing loose kwargs"
         )
     tsqr_mode = config.panel_width is None
+    deaths = 0
     if faults is not None:
         want = FaultSpec if tsqr_mode else _blocked.PanelFaultSchedule
         if not isinstance(faults, want):
@@ -351,7 +358,22 @@ def factorize(
                 f"(panel_width={config.panel_width}), got "
                 f"{type(faults).__name__}"
             )
+        specs = [faults] if tsqr_mode else [*faults.panel.values(),
+                                            *faults.update.values()]
+        deaths = sum(len(spec.deaths) for spec in specs)
+    shape = getattr(a, "shape", ())
+    panels = 0 if tsqr_mode or not shape else -(-shape[-1] // config.panel_width)
+    with _dispatch.span(_dispatch.FACTORIZE, call=next(_CALLS), panels=panels,
+                        deaths=deaths):
+        return _route(a, config, mesh, axis, faults, jit)
 
+
+def _route(a, config: QRConfig, mesh, axis, faults, jit: bool):
+    """The driver ``factorize`` routes ``a`` to (see the module table)."""
+    from . import blocked as _blocked
+    from . import tsqr as _tsqr
+
+    tsqr_mode = config.panel_width is None
     if mesh is not None:
         if config.redundancy is Redundancy.CODED:
             raise ValueError(

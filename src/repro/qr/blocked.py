@@ -395,12 +395,15 @@ def _blocked_body(
     )
     q_cols = []
     trail = a
-    s = kops.panel_cross(a, split=widths[0], **kw)          # pipeline prime
+    with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n):
+        s = kops.panel_cross(a, split=widths[0], **kw)      # pipeline prime
 
     def local_r_of(panel, g):
-        if local_r == "chol":
-            return chol_r(g)                      # free: lookahead Gram
-        return pf.local_fn()(panel.astype(jnp.float32))
+        with _dispatch.span(_dispatch.LOCAL_R, ranks=comm.n_ranks,
+                            rows=m_local, cols=panel.shape[-1]):
+            if local_r == "chol":
+                return chol_r(g)                  # free: lookahead Gram
+            return pf.local_fn()(panel.astype(jnp.float32))
 
     def coded_reduce(payload, plan, combiner):
         p = comm.n_ranks
@@ -432,6 +435,26 @@ def _blocked_body(
                                                  rep.plan_r)
         return r_kk, c_sum, v, v, None
 
+    def reduce_cross(rep, c_loc):
+        """The split schedule's second, serialized sum butterfly of the
+        cross products (its own plan — update-phase deaths strike here),
+        restored from replicas where it lost ranks.  Returns
+        ``(c_sum, valid_w, detected_w)``."""
+        det_w = None
+        if coded:
+            c_sum, valid_w, det_w = coded_reduce(
+                c_loc, rep.plan_w, FUSED_PANEL_COMBINER.parts[1]
+            )
+        else:
+            c_sum, valid_w = ft_allreduce(
+                c_loc, comm, op="sum", plan=rep.plan_w
+            )
+        if rep.recovered_w:
+            c_sum = recover_payload(
+                c_sum, comm, rep.plan_w.final_valid, plan=rep.plan_w
+            )
+        return c_sum, valid_w, det_w
+
     pending = None
     if reports[0].fused:
         b0 = widths[0]
@@ -440,93 +463,83 @@ def _blocked_body(
         )
     c0 = 0
     for rep, b in zip(reports, widths):
-        nt = n - c0 - b
-        panel = trail[..., :, :b]
-        # -- phase 1: panel reduction(s) over the butterfly -----------------
-        if rep.fused:
-            r_kk, c_sum, valid_r, valid_w, det = pending
-            pending = None
-        else:
-            r_loc = local_r_of(panel, s[..., :, :b])
-            if coded:
-                r_kk, valid_r, det = coded_reduce(
-                    r_loc, rep.plan_r, FUSED_PANEL_COMBINER.parts[0]
-                )
+        with _dispatch.span(_dispatch.PANEL, k=rep.panel):
+            nt = n - c0 - b
+            panel = trail[..., :, :b]
+            # -- phase 1: panel reduction(s) over the butterfly -------------
+            if rep.fused:
+                r_kk, c_sum, valid_r, valid_w, det = pending
+                pending = None
             else:
-                r_kk, valid_r = pf.reduce_r_prepared(r_loc, comm, rep.plan_r)
-                det = None
-            c_sum = valid_w = None
-        valid = valid & valid_r
-        if det is not None:
-            detected = detected | det
-        all_valid_r = bool(_data_valid(rep.plan_r).all())
-        if rep.recovered_r:
-            # recover_payload dispatches per scheme: butterfly plans fetch
-            # full replicas from donors; coded plans already reconstructed
-            # in-collective, so it only validates the erasure budget held.
-            if rep.fused and c_sum is not None:
-                # ONE fetch restores both stacked leaves — the replica
-                # copies of the fused payload double as FT copies for R
-                # and the cross products alike.
-                r_kk, c_sum = recover_payload(
-                    (r_kk, c_sum), comm, rep.plan_r.final_valid,
-                    plan=rep.plan_r,
-                )
-            else:
-                r_kk = recover_payload(
-                    r_kk, comm, rep.plan_r.final_valid, plan=rep.plan_r
-                )
-        # -- phase 2: explicit panel Q (+ reorth polish) --------------------
-        # The polish's gram all-reduce mixes every rank's contribution, so
-        # it needs every rank to hold a finite r_kk; when a no-recovery run
-        # left poisoned ranks, skip the polish — survivors keep their exact
-        # unpolished factor instead of inheriting the NaN.
-        clean = all_valid_r or bool(rep.recovered_r)
-        pf_k = pf if clean else dataclasses.replace(pf, reorth=0)
-        q_k, r_tot = pf_k.form_q(panel.astype(jnp.float32), r_kk, comm)
-        q_k = q_k.astype(a.dtype)
-        if compute_q:
-            q_cols.append(q_k)
-        # -- phase 3: block row of R ----------------------------------------
-        if nt:
-            if not rep.fused:
-                # split schedule: the cross products ride a second,
-                # serialized sum butterfly (its own plan — update-phase
-                # deaths strike here)
+                r_loc = local_r_of(panel, s[..., :, :b])
                 if coded:
-                    c_sum, valid_w, det_w = coded_reduce(
-                        s[..., :, b:], rep.plan_w,
-                        FUSED_PANEL_COMBINER.parts[1],
+                    r_kk, valid_r, det = coded_reduce(
+                        r_loc, rep.plan_r, FUSED_PANEL_COMBINER.parts[0]
                     )
-                    detected = detected | det_w
                 else:
-                    c_sum, valid_w = ft_allreduce(
-                        s[..., :, b:], comm, op="sum", plan=rep.plan_w
+                    r_kk, valid_r = pf.reduce_r_prepared(r_loc, comm, rep.plan_r)
+                    det = None
+                c_sum = valid_w = None
+            valid = valid & valid_r
+            if det is not None:
+                detected = detected | det
+            all_valid_r = bool(_data_valid(rep.plan_r).all())
+            if rep.recovered_r:
+                # recover_payload dispatches per scheme: butterfly plans fetch
+                # full replicas from donors; coded plans already reconstructed
+                # in-collective, so it only validates the erasure budget held.
+                if rep.fused and c_sum is not None:
+                    # ONE fetch restores both stacked leaves — the replica
+                    # copies of the fused payload double as FT copies for R
+                    # and the cross products alike.
+                    r_kk, c_sum = recover_payload(
+                        (r_kk, c_sum), comm, rep.plan_r.final_valid,
+                        plan=rep.plan_r,
                     )
-                valid = valid & valid_w
-                if rep.recovered_w:
-                    c_sum = recover_payload(
-                        c_sum, comm, rep.plan_w.final_valid, plan=rep.plan_w
+                else:
+                    r_kk = recover_payload(
+                        r_kk, comm, rep.plan_r.final_valid, plan=rep.plan_r
                     )
-            w = _solve_w(r_tot, c_sum, pad_to=n_pad - widths[0])
-            r_full = r_full.at[..., c0:c0 + b, c0:].set(
-                jnp.concatenate([r_tot, w], axis=-1)
-            )
-            # -- phase 4: one-sweep trailing update + lookahead -------------
-            b2 = widths[rep.panel + 1]
-            trail, s = kops.trailing_update(
-                trail[..., :, b:], q_k, w.astype(a.dtype),
-                next_width=b2, **kw
-            )
-            nxt = reports[rep.panel + 1]
-            if nxt.fused:
-                # double-buffer: the next panel's butterfly launches as
-                # soon as the sweep lands its lookahead accumulators
-                pending = issue(
-                    nxt, trail[..., :, :b2], s[..., :, :b2], s[..., :, b2:]
-                )
-        else:
-            r_full = r_full.at[..., c0:c0 + b, c0:].set(r_tot)
+            # -- phase 2: explicit panel Q (+ reorth polish) ----------------
+            # The polish's gram all-reduce mixes every rank's contribution, so
+            # it needs every rank to hold a finite r_kk; when a no-recovery run
+            # left poisoned ranks, skip the polish — survivors keep their exact
+            # unpolished factor instead of inheriting the NaN.
+            clean = all_valid_r or bool(rep.recovered_r)
+            pf_k = pf if clean else dataclasses.replace(pf, reorth=0)
+            q_k, r_tot = pf_k.form_q(panel.astype(jnp.float32), r_kk, comm)
+            q_k = q_k.astype(a.dtype)
+            if compute_q:
+                q_cols.append(q_k)
+            # -- phase 3: block row of R ------------------------------------
+            if nt:
+                with _dispatch.span(_dispatch.BLOCK_ROW):
+                    if not rep.fused:
+                        c_sum, valid_w, det_w = reduce_cross(rep, s[..., :, b:])
+                        valid = valid & valid_w
+                        if det_w is not None:
+                            detected = detected | det_w
+                    w = _solve_w(r_tot, c_sum, pad_to=n_pad - widths[0])
+                    r_full = r_full.at[..., c0:c0 + b, c0:].set(
+                        jnp.concatenate([r_tot, w], axis=-1)
+                    )
+                # -- phase 4: one-sweep trailing update + lookahead ---------
+                b2 = widths[rep.panel + 1]
+                with _dispatch.span(_dispatch.TRAILING_UPDATE, width=nt):
+                    trail, s = kops.trailing_update(
+                        trail[..., :, b:], q_k, w.astype(a.dtype),
+                        next_width=b2, **kw
+                    )
+                nxt = reports[rep.panel + 1]
+                if nxt.fused:
+                    # double-buffer: the next panel's butterfly launches as
+                    # soon as the sweep lands its lookahead accumulators
+                    pending = issue(
+                        nxt, trail[..., :, :b2], s[..., :, :b2], s[..., :, b2:]
+                    )
+            else:
+                with _dispatch.span(_dispatch.BLOCK_ROW):
+                    r_full = r_full.at[..., c0:c0 + b, c0:].set(r_tot)
         c0 += b
     q = jnp.concatenate(q_cols, axis=-1) if compute_q else None
     return r_full, valid, q, detected
@@ -606,37 +619,42 @@ def _pipeline_body(
               block_rows=block_rows)
 
     def panel_qr(panel, g):
-        if local_r == "chol":
-            r_loc = chol_r(g)
-        else:
-            r_loc = pf.local_fn()(panel.astype(jnp.float32))
+        with _dispatch.span(_dispatch.LOCAL_R, ranks=comm.n_ranks,
+                            rows=panel.shape[-2], cols=b):
+            if local_r == "chol":
+                r_loc = chol_r(g)
+            else:
+                r_loc = pf.local_fn()(panel.astype(jnp.float32))
         r_kk, _ = pf.reduce_r_prepared(r_loc, comm, plan)
         q_k, r_tot = pf.form_q(panel.astype(jnp.float32), r_kk, comm)
         return q_k.astype(a.dtype), r_tot
 
     # -- prime: padded working copy + panel-0 lookahead, one sweep ----------
-    if n_pad == n:
-        awork = a
-        s = kops._panel_cross_raw(a, split=b, **kw)
-    else:
-        awork, s = kops._pad_cross_raw(a, split=b, out_width=n_pad, **kw)
+    with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad):
+        if n_pad == n:
+            awork = a
+            s = kops._panel_cross_raw(a, split=b, **kw)
+        else:
+            awork, s = kops._pad_cross_raw(a, split=b, out_width=n_pad, **kw)
 
     # -- K−1 uniform panels: one traced body, scanned -----------------------
     def step(carry, _):
         awork, s = carry
         q_k, r_tot = panel_qr(awork[..., :, :b], s[..., :, :b])
-        c_sum, _ = ft_allreduce(s[..., :, b:], comm, op="sum", plan=plan)
-        w = _solve_w(r_tot, c_sum)
-        a_new, s_new = kops._trailing_update_raw(
-            awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
-        )
-        # shift left by b: drop the finished panel, keep the width with
-        # fresh zero columns (the pad stays exactly zero inductively).
-        carry = (
-            jnp.concatenate([a_new, jnp.zeros_like(awork[..., :, :b])], -1),
-            jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1),
-        )
-        r_row = jnp.concatenate([r_tot, w], axis=-1)       # (…, b, n_pad)
+        with _dispatch.span(_dispatch.BLOCK_ROW):
+            c_sum, _ = ft_allreduce(s[..., :, b:], comm, op="sum", plan=plan)
+            w = _solve_w(r_tot, c_sum)
+            r_row = jnp.concatenate([r_tot, w], axis=-1)   # (…, b, n_pad)
+        with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
+            a_new, s_new = kops._trailing_update_raw(
+                awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
+            )
+            # shift left by b: drop the finished panel, keep the width with
+            # fresh zero columns (the pad stays exactly zero inductively).
+            carry = (
+                jnp.concatenate([a_new, jnp.zeros_like(awork[..., :, :b])], -1),
+                jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1),
+            )
         return carry, ((r_row, q_k) if compute_q else r_row)
 
     if k_panels > 1:
@@ -650,14 +668,15 @@ def _pipeline_body(
     )
 
     # -- reassemble R (and Q) in original column coordinates ----------------
-    r_full = jnp.zeros(a.shape[:-2] + (n, n), jnp.float32)
-    for k in range(k_panels - 1):
-        c0 = k * b
-        r_full = r_full.at[..., c0:c0 + b, c0:].set(
-            r_rows[k][..., :, :n - c0]
-        )
-    c0 = (k_panels - 1) * b
-    r_full = r_full.at[..., c0:, c0:].set(r_last)
+    with _dispatch.span(_dispatch.BLOCK_ROW):
+        r_full = jnp.zeros(a.shape[:-2] + (n, n), jnp.float32)
+        for k in range(k_panels - 1):
+            c0 = k * b
+            r_full = r_full.at[..., c0:c0 + b, c0:].set(
+                r_rows[k][..., :, :n - c0]
+            )
+        c0 = (k_panels - 1) * b
+        r_full = r_full.at[..., c0:, c0:].set(r_last)
     q = None
     if compute_q:
         q = jnp.concatenate(
@@ -697,9 +716,11 @@ def _pipeline_body_fused(
               block_rows=block_rows)
 
     def local_r_of(panel, g):
-        if local_r == "chol":
-            return chol_r(g)
-        return pf.local_fn()(panel.astype(jnp.float32))
+        with _dispatch.span(_dispatch.LOCAL_R, ranks=comm.n_ranks,
+                            rows=panel.shape[-2], cols=panel.shape[-1]):
+            if local_r == "chol":
+                return chol_r(g)
+            return pf.local_fn()(panel.astype(jnp.float32))
 
     def issue(awork, s):
         # stacked (R, cross) payload of the live panel, one butterfly;
@@ -722,11 +743,12 @@ def _pipeline_body_fused(
         return q_k.astype(a.dtype), r_tot
 
     # -- prime: padded working copy + panel-0 lookahead + first issue -------
-    if n_pad == n:
-        awork = a
-        s = kops._panel_cross_raw(a, split=b, **kw)
-    else:
-        awork, s = kops._pad_cross_raw(a, split=b, out_width=n_pad, **kw)
+    with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad):
+        if n_pad == n:
+            awork = a
+            s = kops._panel_cross_raw(a, split=b, **kw)
+        else:
+            awork, s = kops._pad_cross_raw(a, split=b, out_width=n_pad, **kw)
 
     rows: list = []           # per-panel (…, b, n_pad) R rows, panels 0..K−2
     qs: list = []
@@ -740,18 +762,22 @@ def _pipeline_body_fused(
         def step(carry, _):
             awork, s, r_red, c_red = carry
             q_k, r_tot = consume(awork[..., :, :b], r_red)
-            w = _solve_w(r_tot, c_red)
-            a_new, s_new = kops._trailing_update_raw(
-                awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
-            )
-            # shift left by b: drop the finished panel, keep the width with
-            # fresh zero columns (the pad stays exactly zero inductively)
-            awork = jnp.concatenate(
-                [a_new, jnp.zeros_like(awork[..., :, :b])], -1
-            )
-            s = jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1)
+            with _dispatch.span(_dispatch.BLOCK_ROW):
+                w = _solve_w(r_tot, c_red)
+                r_row = jnp.concatenate([r_tot, w], axis=-1)
+            with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
+                a_new, s_new = kops._trailing_update_raw(
+                    awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b,
+                    **kw
+                )
+                # shift left by b: drop the finished panel, keep the width
+                # with fresh zero columns (the pad stays exactly zero
+                # inductively)
+                awork = jnp.concatenate(
+                    [a_new, jnp.zeros_like(awork[..., :, :b])], -1
+                )
+                s = jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1)
             r_red, c_red = issue(awork, s)
-            r_row = jnp.concatenate([r_tot, w], axis=-1)
             return (awork, s, r_red, c_red), (
                 (r_row, q_k) if compute_q else r_row
             )
@@ -769,14 +795,16 @@ def _pipeline_body_fused(
         # R-only issue at width b_last, so its producing sweep sits outside
         # the scan ------------------------------------------------------
         q_k, r_tot = consume(awork[..., :, :b], r_red)
-        w = _solve_w(r_tot, c_red)
-        a_new, s_new = kops._trailing_update_raw(
-            awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
-        )
+        with _dispatch.span(_dispatch.BLOCK_ROW):
+            w = _solve_w(r_tot, c_red)
+            rows.append(jnp.concatenate([r_tot, w], axis=-1))
+        with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
+            a_new, s_new = kops._trailing_update_raw(
+                awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
+            )
         r_red = issue_last(
             a_new[..., :, :b_last], s_new[..., :b_last, :b_last]
         )
-        rows.append(jnp.concatenate([r_tot, w], axis=-1))
         if compute_q:
             qs.append(q_k)
         awork = a_new             # last panel lives in columns [0, b_last)
@@ -785,12 +813,15 @@ def _pipeline_body_fused(
     q_last, r_last = consume(awork[..., :, :b_last], r_red)
 
     # -- reassemble R (and Q) in original column coordinates ----------------
-    r_full = jnp.zeros(a.shape[:-2] + (n, n), jnp.float32)
-    for k in range(k_panels - 1):
-        c0 = k * b
-        r_full = r_full.at[..., c0:c0 + b, c0:].set(rows[k][..., :, :n - c0])
-    c0 = (k_panels - 1) * b
-    r_full = r_full.at[..., c0:, c0:].set(r_last)
+    with _dispatch.span(_dispatch.BLOCK_ROW):
+        r_full = jnp.zeros(a.shape[:-2] + (n, n), jnp.float32)
+        for k in range(k_panels - 1):
+            c0 = k * b
+            r_full = r_full.at[..., c0:c0 + b, c0:].set(
+                rows[k][..., :, :n - c0]
+            )
+        c0 = (k_panels - 1) * b
+        r_full = r_full.at[..., c0:, c0:].set(r_last)
     q = None
     if compute_q:
         q = jnp.concatenate(qs + [q_last], axis=-1)
@@ -829,7 +860,6 @@ def _compiled_sim_pipeline(
 
 
 def _note_reductions(
-    name: str,
     reports: tuple[PanelReport, ...],
     widths: tuple[int, ...],
     c_widths: tuple[int, ...],
@@ -863,27 +893,21 @@ def _note_reductions(
                 (rep.plan_w, [(b, cw, 4, False)], 0),
             ]
         for plan, leaves, ov in recs:
-            rounds = plan.round_count()
             _traffic.note(
-                "panel_reduce", dispatches=0, rounds=rounds,
+                "panel_reduce", dispatches=0, rounds=plan.round_count(),
                 wire_bytes=wire_scale * plan.bytes_on_wire_stacked(leaves),
                 overlapped=ov,
             )
-            _dispatch.note_rounds(name, rounds)
-            if ov:
-                _dispatch.note_overlap(name, ov)
         if n_reorth:
-            rounds = n_reorth * reorth_plan.round_count()
             _traffic.note(
-                "reorth_reduce", dispatches=0, rounds=rounds,
+                "reorth_reduce", dispatches=0,
+                rounds=n_reorth * reorth_plan.round_count(),
                 wire_bytes=wire_scale * n_reorth
                 * reorth_plan.bytes_on_wire_stacked([(b, b, 4, True)]),
             )
-            _dispatch.note_rounds(name, rounds)
 
 
 def _note_eager_reductions(
-    name: str,
     reports: tuple[PanelReport, ...],
     widths: tuple[int, ...],
     n: int,
@@ -891,22 +915,25 @@ def _note_eager_reductions(
 ) -> None:
     """Collective accounting for one eager (general-driver) factorization:
     cross leaves at their live trailing widths, polish skipped on panels a
-    no-recovery fault left unclean."""
-    c0 = 0
-    c_widths = []
-    for b in widths:
-        c_widths.append(n - c0 - b)
-        c0 += b
-    reorth_counts = tuple(
-        pf.reorth
-        if bool(_data_valid(rep.plan_r).all()) or rep.recovered_r else 0
-        for rep in reports
-    )
-    plan0 = reports[0].plan_r
-    _note_reductions(
-        name, reports, widths, tuple(c_widths), reorth_counts,
-        make_plan("redundant", getattr(plan0, "n_data", plan0.n_ranks)),
-    )
+    no-recovery fault left unclean.  Nothing to do when nothing tracks."""
+    if not _traffic.tracking():
+        return
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        c0 = 0
+        c_widths = []
+        for b in widths:
+            c_widths.append(n - c0 - b)
+            c0 += b
+        reorth_counts = tuple(
+            pf.reorth
+            if bool(_data_valid(rep.plan_r).all()) or rep.recovered_r else 0
+            for rep in reports
+        )
+        plan0 = reports[0].plan_r
+        _note_reductions(
+            reports, widths, tuple(c_widths), reorth_counts,
+            make_plan("redundant", getattr(plan0, "n_data", plan0.n_ranks)),
+        )
 
 
 def _note_pipeline(shape, dtype, widths, traced: int,
@@ -919,44 +946,48 @@ def _note_pipeline(shape, dtype, widths, traced: int,
     own notes are suppressed at trace time; the eager driver remains the
     reference for panel-local accounting).  Collective records ride along:
     one ``panel_reduce`` per butterfly (fused panels: one stacked record at
-    the padded cross width) plus the ``reorth_reduce`` polish."""
+    the padded cross width) plus the ``reorth_reduce`` polish.  Nothing to
+    do when nothing tracks."""
     _dispatch.note_dispatch(PIPELINE_NAME)
-    lead = int(np.prod(shape[:-2], dtype=np.int64))
-    m, n = shape[-2], shape[-1]
-    b, k_panels = widths[0], len(widths)
-    n_pad = b * k_panels
-    it = jnp.dtype(dtype).itemsize
-    if n_pad == n:
-        recs = [("panel_cross", lead * m * n * it, lead * b * n * 4)]
-    else:
-        recs = [(
-            "pad_cross",
-            lead * m * n * it,
-            lead * (m * n_pad * it + b * n_pad * 4),
-        )]
-    nt = n_pad - b
-    for _ in range(k_panels - 1):
-        recs.append((
-            "trailing_update",
-            lead * (m * nt * it + m * b * it + b * nt * it),
-            lead * (m * nt * it + b * nt * 4),
-        ))
-    first = True
-    for op, read, write in recs:
-        _traffic.note(
-            op, sweeps=1, read_bytes=read, write_bytes=write,
-            dispatches=1 if first else 0, traces=traced if first else 0,
+    if not _traffic.tracking():
+        return
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        lead = int(np.prod(shape[:-2], dtype=np.int64))
+        m, n = shape[-2], shape[-1]
+        b, k_panels = widths[0], len(widths)
+        n_pad = b * k_panels
+        it = jnp.dtype(dtype).itemsize
+        if n_pad == n:
+            recs = [("panel_cross", lead * m * n * it, lead * b * n * 4)]
+        else:
+            recs = [(
+                "pad_cross",
+                lead * m * n * it,
+                lead * (m * n_pad * it + b * n_pad * 4),
+            )]
+        nt = n_pad - b
+        for _ in range(k_panels - 1):
+            recs.append((
+                "trailing_update",
+                lead * (m * nt * it + m * b * it + b * nt * it),
+                lead * (m * nt * it + b * nt * 4),
+            ))
+        first = True
+        for op, read, write in recs:
+            _traffic.note(
+                op, sweeps=1, read_bytes=read, write_bytes=write,
+                dispatches=1 if first else 0, traces=traced if first else 0,
+            )
+            first = False
+        p = reports[0].plan_r.n_ranks
+        c_widths = tuple(
+            n_pad - b if k < k_panels - 1 else 0 for k in range(k_panels)
         )
-        first = False
-    p = reports[0].plan_r.n_ranks
-    c_widths = tuple(
-        n_pad - b if k < k_panels - 1 else 0 for k in range(k_panels)
-    )
-    _note_reductions(
-        PIPELINE_NAME, reports, widths, c_widths, (reorth,) * k_panels,
-        make_plan("redundant", p),
-        wire_scale=int(np.prod(shape[:-3], dtype=np.int64)),
-    )
+        _note_reductions(
+            reports, widths, c_widths, (reorth,) * k_panels,
+            make_plan("redundant", p),
+            wire_scale=int(np.prod(shape[:-3], dtype=np.int64)),
+        )
 
 
 def _tuned_config(config: QRConfig, m_local: int, n: int, dtype) -> QRConfig:
@@ -982,15 +1013,17 @@ def _tuned_config(config: QRConfig, m_local: int, n: int, dtype) -> QRConfig:
 
 
 def _run_sim_pipeline(a, widths, config: QRConfig, reports, *, batched=False):
-    config = _tuned_config(config, a.shape[-2], a.shape[-1], a.dtype)
-    fun = _compiled_sim_pipeline(
-        a.shape[-3], widths, config.canonical(), batched
-    )
+    with _dispatch.span(_dispatch.PLAN, plans=0):
+        config = _tuned_config(config, a.shape[-2], a.shape[-1], a.dtype)
+        fun = _compiled_sim_pipeline(
+            a.shape[-3], widths, config.canonical(), batched
+        )
     t0 = _dispatch.trace_count(PIPELINE_NAME)
     # suppress the wrappers' own notes while the body traces (a cqr2 local
     # QR would otherwise record phantom once-per-trace kernel launches);
     # _note_pipeline records the exact per-call totals below.
-    with _traffic.suppress(), _dispatch.suppress():
+    with _traffic.suppress(), _dispatch.suppress(), \
+            _dispatch.span(_dispatch.LAUNCH):
         out = fun(a)
     _note_pipeline(
         a.shape, a.dtype, widths,
@@ -1021,11 +1054,13 @@ def _setup(
             f"tall as the widest panel ({max(widths)}); shrink panel_width "
             "or use fewer ranks"
         )
-    reports = _build_reports(
-        config.variant, p, widths, faults or PanelFaultSchedule(),
-        config.recover, config.fuse, config.redundancy, config.parity,
-    )
-    return widths, reports, config.factorizer()
+    # a plan per panel reduction and per cross-product reduction
+    with _dispatch.span(_dispatch.PLAN, plans=2 * len(widths) - 1):
+        reports = _build_reports(
+            config.variant, p, widths, faults or PanelFaultSchedule(),
+            config.recover, config.fuse, config.redundancy, config.parity,
+        )
+        return widths, reports, config.factorizer()
 
 
 # ---------------------------------------------------------------------------
@@ -1049,7 +1084,8 @@ def _factorize_sim(
         # coded runs always take the eager driver (the scan pipeline's
         # one-plan butterfly schedule is replica-redundancy only;
         # pipeline=ON + coded is rejected at config validation)
-        eager_cfg = _tuned_config(config, m_local, n, a_blocks.dtype)
+        with _dispatch.span(_dispatch.PLAN, plans=0):
+            eager_cfg = _tuned_config(config, m_local, n, a_blocks.dtype)
         r, valid, q, detected = _blocked_body(
             a_blocks, SimComm(p), reports, widths, pf,
             local_r=config.resolved_local_r(), compute_q=config.compute_q,
@@ -1057,7 +1093,7 @@ def _factorize_sim(
             block_rows=eager_cfg.block_rows,
             world=SimComm(p + config.parity) if coded else None,
         )
-        _note_eager_reductions("blocked_qr_sim", reports, widths, n, pf)
+        _note_eager_reductions(reports, widths, n, pf)
     return BlockedQRResult(
         r=r, valid=valid, q=q, reports=reports,
         panel_width=config.panel_width, detected=detected,
@@ -1176,27 +1212,31 @@ def _factorize_shard_map(
     p = mesh.shape[axis]
     m, n = a_global.shape
     widths, reports, pf = _setup(m // p, n, p, config, faults)
-    config = _tuned_config(config, m // p, n, a_global.dtype)
-    if _resolve_pipeline(config.pipeline, reports):
-        fun = _compiled_shard_pipeline(
-            mesh, axis, p, widths, config.canonical(), jit
-        )
+    with _dispatch.span(_dispatch.PLAN, plans=0):
+        config = _tuned_config(config, m // p, n, a_global.dtype)
+        pipelined = _resolve_pipeline(config.pipeline, reports)
+        if pipelined:
+            fun = _compiled_shard_pipeline(
+                mesh, axis, p, widths, config.canonical(), jit
+            )
+        else:
+            fun = _compiled_shard_general(
+                mesh, axis, p, reports, widths, config.canonical(), jit
+            )
+    if pipelined:
         t0 = _dispatch.trace_count(PIPELINE_NAME)
-        with _traffic.suppress(), _dispatch.suppress():
+        with _traffic.suppress(), _dispatch.suppress(), \
+                _dispatch.span(_dispatch.LAUNCH):
             r, valid, q = fun(a_global)
         _note_pipeline(
             (p, m // p, n), a_global.dtype, widths,
             _dispatch.trace_count(PIPELINE_NAME) - t0, reports, pf.reorth,
         )
     else:
-        fun = _compiled_shard_general(
-            mesh, axis, p, reports, widths, config.canonical(), jit
-        )
         _dispatch.note_dispatch("blocked_qr_shard_map")
-        r, valid, q = fun(a_global)
-        _note_eager_reductions(
-            "blocked_qr_shard_map", reports, widths, n, pf
-        )
+        with _dispatch.span(_dispatch.LAUNCH):
+            r, valid, q = fun(a_global)
+        _note_eager_reductions(reports, widths, n, pf)
     return BlockedQRResult(
         r=r, valid=valid, q=(q if config.compute_q else None),
         reports=reports, panel_width=config.panel_width,

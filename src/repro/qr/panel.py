@@ -39,6 +39,7 @@ from repro.collective.combiners import (
 from repro.collective.comm import Comm
 from repro.collective.engine import execute_plan, ft_allreduce
 from repro.collective.plan import Plan
+from repro.kernels import dispatch as _dispatch
 from repro.kernels.backend import F32_PRECISION
 
 __all__ = [
@@ -152,14 +153,16 @@ def form_q(a_blocks, r, comm: Comm, reorth: int = 1):
         )
         return jnp.swapaxes(y, -1, -2)
 
-    q = solve_r(a_blocks, r)
-    for _ in range(reorth):
-        g = jnp.matmul(jnp.swapaxes(q, -1, -2), q, precision=F32_PRECISION)
-        g_sum, _ = ft_allreduce(g, comm, op="gram_sum")
-        r2 = _posdiag(jnp.swapaxes(jnp.linalg.cholesky(g_sum), -1, -2))
-        q = solve_r(q, r2)
-        r = _posdiag(jnp.matmul(r2, r, precision=F32_PRECISION))
-    return q, r
+    with _dispatch.span(_dispatch.FORM_Q, reorth=reorth):
+        q = solve_r(a_blocks, r)
+        for _ in range(reorth):
+            g = jnp.matmul(jnp.swapaxes(q, -1, -2), q,
+                           precision=F32_PRECISION)
+            g_sum, _ = ft_allreduce(g, comm, op="gram_sum")
+            r2 = _posdiag(jnp.swapaxes(jnp.linalg.cholesky(g_sum), -1, -2))
+            q = solve_r(q, r2)
+            r = _posdiag(jnp.matmul(r2, r, precision=F32_PRECISION))
+        return q, r
 
 
 # ---------------------------------------------------------------------------
@@ -188,9 +191,13 @@ class PanelFactorizer:
         return QRCombiner(self.local_fn())
 
     def reduce_r(self, a_panel, comm: Comm, plan: Plan, *, fast=None):
-        """Butterfly-reduce the panel to its global R: local QR (``prepare``)
-        then ``QR([R_lo; R_hi])`` per level.  Returns ``(r, valid)``."""
-        return execute_plan(a_panel, comm, plan, self.combiner(), fast=fast)
+        """Butterfly-reduce the panel to its global R: local QR (the QR
+        combiner's ``prepare``, run here so that its span is its own) then
+        ``QR([R_lo; R_hi])`` per level.  Returns ``(r, valid)``."""
+        with _dispatch.span(_dispatch.LOCAL_R, ranks=comm.n_ranks,
+                            rows=a_panel.shape[-2], cols=a_panel.shape[-1]):
+            r_local = self.combiner().tree_prepare(a_panel)
+        return self.reduce_r_prepared(r_local, comm, plan, fast=fast)
 
     def reduce_r_prepared(self, r_local, comm: Comm, plan: Plan, *, fast=None):
         """Same reduction, but the local R factors are already computed

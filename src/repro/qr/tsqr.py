@@ -183,16 +183,18 @@ def _factorize_sim_coded(
     from repro.collective.coded import make_coded_plan
 
     p = a_blocks.shape[0]
-    plan = make_coded_plan(p, config.parity, fault_spec)
-    if config.compute_q and not plan.final_valid[:p].all():
-        raise ValueError(
-            "compute_q requires every data rank to end valid; this fault "
-            f"spec exceeds the coded erasure budget (c={config.parity}) — "
-            f"final_valid={plan.final_valid[:p]}"
-        )
-    fun = _compiled_tsqr_coded(config.canonical(), plan)
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        plan = make_coded_plan(p, config.parity, fault_spec)
+        if config.compute_q and not plan.final_valid[:p].all():
+            raise ValueError(
+                "compute_q requires every data rank to end valid; this fault "
+                f"spec exceeds the coded erasure budget (c={config.parity}) — "
+                f"final_valid={plan.final_valid[:p]}"
+            )
+        fun = _compiled_tsqr_coded(config.canonical(), plan)
     _dispatch.note_dispatch("tsqr_coded")
-    r, valid, q, detected = fun(a_blocks, observed)
+    with _dispatch.span(_dispatch.LAUNCH):
+        r, valid, q, detected = fun(a_blocks, observed)
     return TSQRResult(
         r=r, valid=valid, q=(q if config.compute_q else None), plan=plan,
         detected=detected,
@@ -224,15 +226,16 @@ def _factorize_sim(
             "coded scheme can act on; use redundancy='coded'"
         )
     p = a_blocks.shape[0]
-    plan = make_plan(config.variant, p, fault_spec)
-    if config.compute_q and not plan.final_valid.all():
-        raise ValueError(
-            "compute_q requires an all-valid plan (fault-free, or "
-            "self-healing within tolerance); got final_valid="
-            f"{plan.final_valid}"
-        )
-    comm = SimComm(p)
-    pf = config.factorizer()
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        plan = make_plan(config.variant, p, fault_spec)
+        if config.compute_q and not plan.final_valid.all():
+            raise ValueError(
+                "compute_q requires an all-valid plan (fault-free, or "
+                "self-healing within tolerance); got final_valid="
+                f"{plan.final_valid}"
+            )
+        comm = SimComm(p)
+        pf = config.factorizer()
     r, valid = pf.reduce_r(a_blocks, comm, plan)
     q = None
     if config.compute_q:
@@ -267,14 +270,16 @@ def _factorize_batched(a_batch, config: QRConfig) -> TSQRResult:
             f"a_batch must be (B, P, m_local, n), got shape {a_batch.shape}"
         )
     p = a_batch.shape[1]
-    fun, plan = _compiled_tsqr_batched(p, config.canonical())
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        fun, plan = _compiled_tsqr_batched(p, config.canonical())
     if config.compute_q and not plan.final_valid.all():
         raise ValueError(
             "compute_q requires an all-valid plan; variant "
             f"{config.variant!r} leaves ranks invalid even fault-free"
         )
     _dispatch.note_dispatch("tsqr_batched")
-    r, valid, q = fun(a_batch)
+    with _dispatch.span(_dispatch.LAUNCH):
+        r, valid, q = fun(a_batch)
     return TSQRResult(r=r, valid=valid, q=q, plan=plan)
 
 
@@ -301,11 +306,13 @@ def _factorize_gram_shard(
     certified for κ(A) ≲ 1/√ε like CQR2.
     """
     p = mesh.shape[axis]
-    fun = _compiled_tsqr_gram_shard(mesh, axis, p, config.reorth, jit)
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        fun = _compiled_tsqr_gram_shard(mesh, axis, p, config.reorth, jit)
+        plan = make_plan("redundant", p)
     _dispatch.note_dispatch("tsqr_gram_shard_map")
-    r, q = fun(a_global)
-    return TSQRResult(r=r, valid=jnp.ones((p,), bool), q=q,
-                      plan=make_plan("redundant", p))
+    with _dispatch.span(_dispatch.LAUNCH):
+        r, q = fun(a_global)
+    return TSQRResult(r=r, valid=jnp.ones((p,), bool), q=q, plan=plan)
 
 
 def _factorize_shard(
@@ -327,16 +334,19 @@ def _factorize_shard(
     change (step-boundary replanning, DESIGN.md §2).
     """
     p = mesh.shape[axis]
-    plan = make_plan(config.variant, p, fault_spec)
-    if config.compute_q and not plan.final_valid.all():
-        raise ValueError(
-            "compute_q requires an all-valid plan (fault-free, or "
-            "self-healing within tolerance)"
-        )
-    pf = config.factorizer()
-    fun = _compiled_tsqr_shard(mesh, axis, plan, pf, config.compute_q, jit)
+    with _dispatch.span(_dispatch.PLAN, plans=1):
+        plan = make_plan(config.variant, p, fault_spec)
+        if config.compute_q and not plan.final_valid.all():
+            raise ValueError(
+                "compute_q requires an all-valid plan (fault-free, or "
+                "self-healing within tolerance)"
+            )
+        pf = config.factorizer()
+        fun = _compiled_tsqr_shard(mesh, axis, plan, pf, config.compute_q,
+                                   jit)
     _dispatch.note_dispatch("tsqr_shard_map")
-    r, valid, q = fun(a_global)
+    with _dispatch.span(_dispatch.LAUNCH):
+        r, valid, q = fun(a_global)
     return TSQRResult(
         r=r, valid=valid, q=(q if config.compute_q else None), plan=plan
     )

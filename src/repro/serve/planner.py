@@ -9,7 +9,8 @@ shard), instantiated on this repo's own accounting:
     the prime sweep (``pad_cross``) plus ``K − 1`` fused trailing sweeps
     at the padded maximal width, the quantities the roofline report and
     the ``general_qr`` bench case gate.
-  * **Collective rounds** mirror ``repro.kernels.dispatch.note_rounds``:
+  * **Collective rounds** mirror the drivers' ``panel_reduce`` records
+    (``repro.kernels.traffic.KernelTraffic.rounds_of``):
     the fused schedule ships ONE stacked butterfly per panel, so a
     factorization costs ``K · log₂P`` serial rounds (Langou's
     single-reduce ideal per panel, PR 6's hard gate).
