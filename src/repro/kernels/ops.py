@@ -212,14 +212,40 @@ def _ref_pad_cross_jit(a, *, split: int, out_width: int):
     return _ref.pad_cross(a, split=split, out_width=out_width)
 
 
-def _trailing_update_raw(a, q, w, *, next_width: int = 0,
+def _shift_left(x, shift: int):
+    """Append ``shift`` zero columns to the updated block(s)."""
+    return jax.tree.map(
+        lambda y: jnp.concatenate(
+            [y, jnp.zeros(y.shape[:-1] + (shift,), y.dtype)], axis=-1
+        ),
+        x,
+    )
+
+
+def _trailing_update_raw(a, q, w, *, next_width: int = 0, shift: int = 0,
                          use_pallas: bool = False,
                          interpret: bool | None = None,
                          block_rows: int | None = None):
+    """``shift > 0``: update ``a[..., shift:]`` and return it (and ``S``)
+    shifted left into ``a``'s full width, the freed columns zero.  The
+    Pallas path does it inside the kernel's one sweep; the jnp oracle
+    slices and concatenates around its own jit, so its program is the
+    unshifted one — XLA would round a width-1 product differently once
+    the concatenate shared the program."""
     if use_pallas:
+        if shift:
+            return _batched(_trailing_mod.trailing_update_shift, 3)(
+                a, q, w, shift=shift, next_width=next_width,
+                interpret=interpret, block_rows=block_rows,
+            )
         return _batched(_trailing_mod.trailing_update, 3)(
             a, q, w, next_width=next_width, interpret=interpret,
             block_rows=block_rows,
+        )
+    if shift:
+        return _shift_left(
+            _ref_trailing_jit(a[..., shift:], q, w, next_width=next_width),
+            shift,
         )
     return _ref_trailing_jit(a, q, w, next_width=next_width)
 

@@ -107,6 +107,14 @@ class KernelTraffic:
         )
 
     @property
+    def shifted_sweeps(self) -> int:
+        """Trailing-update sweeps that wrote their block back shifted left
+        by the finished panel's width, in the same pass (the scan
+        pipeline's padded working matrix), rather than into a separate
+        narrower array that XLA then slices and re-pads."""
+        return sum(r["shifted"] for r in self.records)
+
+    @property
     def overlapped(self) -> int:
         """Reductions issued against lookahead accumulators *during* the
         previous panel's trailing sweep (the double-buffered pipeline's
@@ -124,6 +132,7 @@ class KernelTraffic:
             "collective_rounds": self.collective_rounds,
             "wire_bytes": self.wire_bytes,
             "overlapped": self.overlapped,
+            "shifted_sweeps": self.shifted_sweeps,
             "ops": [r["op"] for r in self.records],
         }
 
@@ -134,7 +143,8 @@ _SUPPRESS: list[bool] = []
 
 def note(op: str, *, sweeps: int = 0, read_bytes: int = 0,
          write_bytes: int = 0, dispatches: int = 1, traces: int = 0,
-         rounds: int = 0, wire_bytes: int = 0, overlapped: int = 0) -> None:
+         rounds: int = 0, wire_bytes: int = 0, overlapped: int = 0,
+         shifted: int = 0) -> None:
     """Record one kernel invocation into every active tracker (no-op when
     nothing is tracking — the hot path pays one list check).
 
@@ -150,6 +160,9 @@ def note(op: str, *, sweeps: int = 0, read_bytes: int = 0,
     drivers note one ``panel_reduce`` record per butterfly with
     ``dispatches=0, sweeps=0`` so the collective accounting never perturbs
     the HBM-sweep and single-dispatch gates.
+
+    ``shifted`` marks a trailing-update sweep that wrote its block back
+    shifted left in the same pass (:attr:`KernelTraffic.shifted_sweeps`).
     """
     if not _ACTIVE or _SUPPRESS:
         return
@@ -163,6 +176,7 @@ def note(op: str, *, sweeps: int = 0, read_bytes: int = 0,
         "rounds": int(rounds),
         "wire_bytes": int(wire_bytes),
         "overlapped": int(overlapped),
+        "shifted": int(shifted),
     }
     for t in _ACTIVE:
         t.records.append(rec)

@@ -555,10 +555,13 @@ def _blocked_body(
 # (zero columns on the right, produced in-kernel by the column-masked
 # ``pad_cross`` prime), and after each panel the trailing block is shifted
 # left by b — the live panel is always columns [0, b), the trailing block
-# always columns [b, n_pad).  Every scan iteration therefore has identical
-# shapes, one ``lax.scan`` trace covers all K−1 uniform panels (the ragged
-# last panel is a static epilogue in the same program), and the whole
-# factorization compiles to ONE device program that never retraces.  Zero
+# always columns [b, n_pad).  The update kernel does the shift in its one
+# sweep (``trailing_update_shift``: it reads the full width and writes back
+# in place, shifted, the freed columns zeroed), so no separate slice or pad
+# pass over the working matrix exists.  Every scan iteration therefore has
+# identical shapes, one ``lax.scan`` trace covers all K−1 uniform panels
+# (the ragged last panel is a static epilogue in the same program), and the
+# whole factorization compiles to ONE device program that never retraces.  Zero
 # pad columns ride every sweep without perturbing the real columns: the
 # results are bit-identical to the eager driver (hypothesis-swept).
 # ---------------------------------------------------------------------------
@@ -646,14 +649,11 @@ def _pipeline_body(
             w = _solve_w(r_tot, c_sum)
             r_row = jnp.concatenate([r_tot, w], axis=-1)   # (…, b, n_pad)
         with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
-            a_new, s_new = kops._trailing_update_raw(
-                awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
-            )
-            # shift left by b: drop the finished panel, keep the width with
-            # fresh zero columns (the pad stays exactly zero inductively).
-            carry = (
-                jnp.concatenate([a_new, jnp.zeros_like(awork[..., :, :b])], -1),
-                jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1),
+            # shift left by b in the sweep: drop the finished panel, keep
+            # the width with fresh zero columns (the pad stays exactly zero
+            # inductively)
+            carry = kops._trailing_update_raw(
+                awork, q_k, w.astype(a.dtype), next_width=b, shift=b, **kw
             )
         return carry, ((r_row, q_k) if compute_q else r_row)
 
@@ -766,17 +766,13 @@ def _pipeline_body_fused(
                 w = _solve_w(r_tot, c_red)
                 r_row = jnp.concatenate([r_tot, w], axis=-1)
             with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
-                a_new, s_new = kops._trailing_update_raw(
-                    awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b,
+                # shift left by b in the sweep: drop the finished panel,
+                # keep the width with fresh zero columns (the pad stays
+                # exactly zero inductively)
+                awork, s = kops._trailing_update_raw(
+                    awork, q_k, w.astype(a.dtype), next_width=b, shift=b,
                     **kw
                 )
-                # shift left by b: drop the finished panel, keep the width
-                # with fresh zero columns (the pad stays exactly zero
-                # inductively)
-                awork = jnp.concatenate(
-                    [a_new, jnp.zeros_like(awork[..., :, :b])], -1
-                )
-                s = jnp.concatenate([s_new, jnp.zeros_like(s[..., :, :b])], -1)
             r_red, c_red = issue(awork, s)
             return (awork, s, r_red, c_red), (
                 (r_row, q_k) if compute_q else r_row
@@ -799,15 +795,13 @@ def _pipeline_body_fused(
             w = _solve_w(r_tot, c_red)
             rows.append(jnp.concatenate([r_tot, w], axis=-1))
         with _dispatch.span(_dispatch.TRAILING_UPDATE, width=n_pad - b):
-            a_new, s_new = kops._trailing_update_raw(
-                awork[..., :, b:], q_k, w.astype(a.dtype), next_width=b, **kw
+            awork, s = kops._trailing_update_raw(
+                awork, q_k, w.astype(a.dtype), next_width=b, shift=b, **kw
             )
-        r_red = issue_last(
-            a_new[..., :, :b_last], s_new[..., :b_last, :b_last]
-        )
+        # the last panel lives in columns [0, b_last)
+        r_red = issue_last(awork[..., :, :b_last], s[..., :b_last, :b_last])
         if compute_q:
             qs.append(q_k)
-        awork = a_new             # last panel lives in columns [0, b_last)
 
     # -- epilogue: consume the last carried reduction -----------------------
     q_last, r_last = consume(awork[..., :, :b_last], r_red)
@@ -937,14 +931,19 @@ def _note_eager_reductions(
 
 
 def _note_pipeline(shape, dtype, widths, traced: int,
-                   reports: tuple[PanelReport, ...], reorth: int) -> None:
+                   reports: tuple[PanelReport, ...], reorth: int,
+                   shifted: bool) -> None:
     """Per-call traffic/dispatch accounting for the pipeline (the kernels
     inside the scan are traced once but *execute* once per panel, so the
-    wrapper records the exact per-call totals: K sweeps, 1 dispatch).  Only
-    the trailing path is modeled — a ``cqr2``/``cqr2_pallas`` local QR adds
-    narrow (m×b) panel-local sweeps that are not recorded (their wrappers'
-    own notes are suppressed at trace time; the eager driver remains the
-    reference for panel-local accounting).  Collective records ride along:
+    wrapper records the exact per-call totals: K sweeps, 1 dispatch).
+    ``shifted`` (the Pallas path): each update streams the whole padded
+    working matrix and writes it back shifted; otherwise the jnp update
+    streams the sliced trailing block, and XLA's slice and re-pad passes
+    around it are not recorded.  Only the trailing path is modeled — a
+    ``cqr2``/``cqr2_pallas`` local QR adds narrow (m×b) panel-local sweeps
+    that are not recorded (their wrappers' own notes are suppressed at
+    trace time; the eager driver remains the reference for panel-local
+    accounting).  Collective records ride along:
     one ``panel_reduce`` per butterfly (fused panels: one stacked record at
     the padded cross width) plus the ``reorth_reduce`` polish.  Nothing to
     do when nothing tracks."""
@@ -966,17 +965,19 @@ def _note_pipeline(shape, dtype, widths, traced: int,
                 lead * (m * n_pad * it + b * n_pad * 4),
             )]
         nt = n_pad - b
+        swept = n_pad if shifted else nt       # columns A streams through
         for _ in range(k_panels - 1):
             recs.append((
                 "trailing_update",
-                lead * (m * nt * it + m * b * it + b * nt * it),
-                lead * (m * nt * it + b * nt * 4),
+                lead * (m * swept * it + m * b * it + b * nt * it),
+                lead * (m * swept * it + b * nt * 4),
             ))
         first = True
         for op, read, write in recs:
             _traffic.note(
                 op, sweeps=1, read_bytes=read, write_bytes=write,
                 dispatches=1 if first else 0, traces=traced if first else 0,
+                shifted=int(shifted and op == "trailing_update"),
             )
             first = False
         p = reports[0].plan_r.n_ranks
@@ -1028,6 +1029,7 @@ def _run_sim_pipeline(a, widths, config: QRConfig, reports, *, batched=False):
     _note_pipeline(
         a.shape, a.dtype, widths,
         _dispatch.trace_count(PIPELINE_NAME) - t0, reports, config.reorth,
+        config.use_pallas,
     )
     return out
 
@@ -1231,6 +1233,7 @@ def _factorize_shard_map(
         _note_pipeline(
             (p, m // p, n), a_global.dtype, widths,
             _dispatch.trace_count(PIPELINE_NAME) - t0, reports, pf.reorth,
+            config.use_pallas,
         )
     else:
         _dispatch.note_dispatch("blocked_qr_shard_map")

@@ -5,10 +5,10 @@ defaults — the same shape-wise planning idea as torchrec's
 ``EmbeddingPerfEstimator`` (perf = comms + compute + HBM sweeps per
 shard), instantiated on this repo's own accounting:
 
-  * **HBM bytes** mirror ``repro.qr.blocked._note_pipeline`` exactly —
-    the prime sweep (``pad_cross``) plus ``K − 1`` fused trailing sweeps
-    at the padded maximal width, the quantities the roofline report and
-    the ``general_qr`` bench case gate.
+  * **HBM bytes** mirror ``repro.qr.blocked._note_pipeline`` exactly for
+    the jnp update the server runs (``shifted=False``) — the prime sweep
+    (``pad_cross``) plus ``K − 1`` fused trailing sweeps at the padded
+    maximal trailing width, the quantities the roofline report gates.
   * **Collective rounds** mirror the drivers' ``panel_reduce`` records
     (``repro.kernels.traffic.KernelTraffic.rounds_of``):
     the fused schedule ships ONE stacked butterfly per panel, so a
@@ -108,8 +108,8 @@ def _pipeline_bytes(
     p: int, m_local: int, n: int, widths: tuple[int, ...]
 ) -> int:
     """HBM bytes of one scan-pipeline factorization — the same per-sweep
-    formulas ``_note_pipeline`` records (prime + K−1 trailing sweeps at the
-    padded maximal trailing width)."""
+    formulas ``_note_pipeline`` records for the unshifted update (prime +
+    K−1 trailing sweeps at the padded maximal trailing width)."""
     b, k_panels = widths[0], len(widths)
     n_pad = b * k_panels
     total = p * m_local * n * _F32                      # prime read

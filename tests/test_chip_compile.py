@@ -12,6 +12,8 @@ one process at a time may load the TPU compiler's library, so every test
 worker must collect the same tests and only the one running this file
 loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -108,6 +110,42 @@ def test_trailing_update_compiles(spec, nt, next_width):
         ),
         spec(1024, nt), spec(1024, 128), spec(128, nt),
     )
+
+
+@pytest.mark.parametrize(
+    "m,n,b,next_width,dtype",
+    [
+        (1 << 18, 128, 32, 32, jnp.float32),    # 100 columns by panels of 32
+        (1 << 18, 128, 32, 0, jnp.float32),
+        (1024, 512, 128, 128, jnp.float32),
+        (1024, 128, 32, 32, jnp.bfloat16),
+    ],
+)
+def test_trailing_update_shift_compiles(spec, m, n, b, next_width, dtype):
+    """The scan pipeline's update: a lane-offset read of the padded
+    working matrix, written back shifted and aliased in place."""
+    _compile_mosaic(
+        lambda a, q, w: tu.trailing_update_shift(
+            a, q, w, shift=b, next_width=next_width, interpret=False
+        ),
+        spec(m, n, dtype=dtype), spec(m, b, dtype=dtype),
+        spec(b, n - b, dtype=dtype),
+    )
+
+
+def test_blocked_pipeline_program_compiles(spec):
+    """The whole fault-free blocked QR at 2^20 × 100 over 4 simulated
+    ranks, panels of 32: one program whose trailing updates shift the
+    working matrix themselves — XLA neither slices the trailing block out
+    nor pads the result back."""
+    cfg = QRConfig(panel_width=32, use_pallas=True, interpret=False)
+    compiled = _compile_mosaic(
+        lambda a: factorize(a, cfg).r, spec(4, 1 << 18, 100)
+    )
+    text = compiled.as_text()
+    assert "trailing_update_shift" in text
+    moves = re.findall(r"= (f32\[[\d,]+\])\S* (?:slice|pad|concatenate)\(", text)
+    assert not {"f32[4,262144,96]", "f32[4,262144,128]"} & set(moves)
 
 
 def test_panel_cross_compiles(spec):

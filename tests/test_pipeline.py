@@ -17,6 +17,8 @@
     ``make_plan``, cached ``Plan.is_fault_free``, the ``pad_cross`` kernel
     vs its oracle, and the dispatch/trace counters.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,6 +137,75 @@ def test_pipeline_acceptance_shape_bit_identical(rng):
     blocked_qr_sim(blocks, panel_width=128, compute_q=True, pipeline="on")
     assert dispatch.trace_count(PIPELINE_NAME) == t1
     assert t1 - t0 <= 1
+
+
+def _eqns(jaxpr, in_scan=False):
+    """Every equation of ``jaxpr`` and of the bodies nested in it (not
+    inside Pallas kernels), with whether a ``scan`` body holds it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_scan
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(
+                        inner, in_scan or eqn.primitive.name == "scan"
+                    )
+
+
+@pytest.mark.parametrize("fuse", ["auto", "off"])
+def test_pipeline_shifts_inside_the_update_kernel(fuse):
+    """The scan pipeline shifts its padded working matrix inside the
+    trailing-update kernel: neither the scan body nor the static stage
+    after it slices out the trailing block or pads the result back to the
+    working width — the two full passes over HBM per panel that the
+    separate slice and concatenate made."""
+    from repro.qr import QRConfig, factorize
+
+    p, m_local, n, pw = 4, 512, 20, 6
+    n_pad = pw * 4                                 # widths (6, 6, 6, 2)
+    cfg = QRConfig(panel_width=pw, use_pallas=True, interpret=True,
+                   pipeline="on", fuse=fuse)
+    a = jnp.zeros((p, m_local, n), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: factorize(x, cfg).r)(a).jaxpr
+    working = {(p, m_local, n_pad), (p, m_local, n_pad - pw)}
+    moves, kernels, in_scan = [], [], False
+    for eqn, scanned in _eqns(jaxpr):
+        in_scan |= scanned
+        name = eqn.primitive.name
+        shapes = {tuple(v.aval.shape) for v in eqn.outvars}
+        if name in ("pad", "concatenate", "slice", "dynamic_slice") and (
+            shapes & working
+        ):
+            moves.append((name, shapes))
+        if name == "pallas_call" and eqn.params["name"] == (
+            "trailing_update_shift"
+        ):
+            kernels.append(scanned)
+    assert in_scan and not moves
+    # one shifted update traced into the scan body; the fused schedule's
+    # static penultimate stage runs one more outside it
+    assert sorted(kernels) == ([False, True] if fuse == "auto" else [True])
+
+    with traffic.track_traffic() as t:
+        factorize(_blocks(np.random.default_rng(0), p, 64, n), cfg)
+    assert t.shifted_sweeps == 3
+    assert t.sweeps_of("pad_cross", "trailing_update") == 4
+    # the jnp path slices and re-pads around an unshifted update
+    with traffic.track_traffic() as t:
+        factorize(_blocks(np.random.default_rng(0), p, 64, n),
+                  dataclasses.replace(cfg, use_pallas=False))
+    assert t.shifted_sweeps == 0
+    assert t.sweeps_of("pad_cross", "trailing_update") == 4
+    # the acceptance shape: still one sweep per panel, 3 of them shifted
+    with traffic.track_traffic() as t:
+        factorize(_blocks(np.random.default_rng(1), 8, 512, 512),
+                  QRConfig(panel_width=128, pipeline="on", use_pallas=True,
+                           interpret=True, fuse=fuse))
+    assert t.sweeps_of("panel_cross", "pad_cross", "trailing_update") == 4
+    assert t.shifted_sweeps == 3
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,8 @@
     stored output) and the pure-jnp oracle (tolerance), across dtypes
     (bf16/f32), ragged shapes (m, n_trail, panel widths not multiples of
     the block size), streaming block sizes, and batch dims;
+  * the shifted update the scan pipeline runs against slice → update →
+    zero pad, bit for bit, on both kernel paths;
   * the blocked driver end-to-end against the dense numpy QR over ragged
     m/n/panel-width combinations.
 
@@ -76,6 +78,45 @@ def test_fused_lookahead_bit_matches_separate_cross(
         np.asarray(a_new, np.float32), np.asarray(a_sep, np.float32)
     )
     assert np.array_equal(np.asarray(s), np.asarray(s_sep))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("dt", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "lead,b,n_pad,n,next_width",
+    [
+        ((), 1, 3, 3, 1),
+        ((4,), 1, 4, 4, 1),
+        ((), 6, 18, 18, 6),
+        ((4,), 6, 24, 20, 6),
+        ((), 32, 96, 96, 32),
+        ((4,), 32, 128, 100, 32),     # the 100-column, 32-panel layout
+        ((), 6, 24, 20, 0),           # no next panel
+        ((4,), 32, 128, 100, 0),
+    ],
+)
+def test_shifted_update_bit_matches_slice_then_pad(
+    use_pallas, dt, lead, b, n_pad, n, next_width
+):
+    """The scan pipeline's update at ``shift=b`` on its ``n_pad``-wide
+    working matrix (zero past column ``n``) == the trailing block sliced
+    out, updated, and padded back with ``b`` zero columns — bit for bit,
+    in ``A_new`` and ``S``, at a row count ragged against the panels."""
+    m, block_rows = 77, 32
+    awork = _arr(b * n, lead + (m, n_pad), dt).at[..., n:].set(0)
+    q = _arr(b + 1, lead + (m, b), dt)
+    w = _arr(b + 2, lead + (b, n_pad - b), dt)
+    kw = dict(next_width=next_width, use_pallas=use_pallas,
+              block_rows=block_rows)
+    got = ops._trailing_update_raw(awork, q, w, shift=b, **kw)
+    want = ops._trailing_update_raw(awork[..., b:], q, w, **kw)
+    if not next_width:
+        got, want = (got,), (want,)
+    for g, x in zip(got, want):
+        x = jnp.concatenate([x, jnp.zeros(x.shape[:-1] + (b,), x.dtype)], -1)
+        assert g.shape == x.shape and g.dtype == x.dtype
+        assert np.array_equal(np.asarray(g, np.float32),
+                              np.asarray(x, np.float32))
 
 
 @given(
